@@ -206,7 +206,6 @@ def run_chaos_worker(spec: ChaosSpec, timeout: int = 1800) -> Dict:
     env["XLA_FLAGS"] = (
         f"--xla_force_host_platform_device_count={spec.devices}")
     env["PYTHONPATH"] = os.path.join(ROOT, "src") + os.pathsep + ROOT
-    env["JAX_PLATFORMS"] = "cpu"
     env.setdefault("REPRO_COST_MODEL", "off")
     out, attempts = _run_subprocess_retry(
         [sys.executable, "-m", "benchmarks.chaos", "--worker"],

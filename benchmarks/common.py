@@ -233,7 +233,6 @@ def run_worker(spec: SweepSpec, timeout: int = 3000) -> List[Dict]:
     env["XLA_FLAGS"] = (
         f"--xla_force_host_platform_device_count={spec.devices}")
     env["PYTHONPATH"] = os.path.join(ROOT, "src") + os.pathsep + ROOT
-    env["JAX_PLATFORMS"] = "cpu"
     out, attempts = _run_subprocess_retry(
         [sys.executable, "-m", "benchmarks._worker"],
         what=f"sweep worker ({spec.runtime}, {spec.devices}d)",
@@ -264,7 +263,6 @@ def calibrate_worker(devices: int, payload: int = 64, *, smoke: bool = False,
         cmd.append("--smoke")
     env = dict(os.environ)
     env["PYTHONPATH"] = os.path.join(ROOT, "src") + os.pathsep + ROOT
-    env["JAX_PLATFORMS"] = "cpu"
     env.pop("XLA_FLAGS", None)  # the probes CLI sets its own forcing flag
     res, attempts = _run_subprocess_retry(
         cmd, what=f"calibration ({devices}d)", env=env, timeout=timeout)
@@ -300,7 +298,6 @@ def gather_impl_worker(devices: int, widths: Tuple[int, ...],
     env["XLA_FLAGS"] = (
         f"--xla_force_host_platform_device_count={devices}")
     env["PYTHONPATH"] = os.path.join(ROOT, "src") + os.pathsep + ROOT
-    env["JAX_PLATFORMS"] = "cpu"
     out, _ = _run_subprocess_retry(
         [sys.executable, "-c", code],
         what=f"gather transport probe ({devices}d)", env=env,

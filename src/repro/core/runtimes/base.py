@@ -88,7 +88,7 @@ class EnsembleLaunchPlan:
     #: descriptive schedule kind ("stacked" / "stepwise")
     kind: str = ""
     #: zero-arg callable reporting the launch executable's compile-cache
-    #: entry count (jit ``_cache_size`` when the jax build exposes it);
+    #: entry count (the launch jit's ``_cache_size``);
     #: the serving fabric asserts it stays flat across membership churn —
     #: the no-recompile contract of act-mask evict/admit. None when the
     #: schedule cannot count compiles.

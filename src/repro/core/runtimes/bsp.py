@@ -22,10 +22,10 @@ from typing import Callable, List
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import shard_map
 from jax.sharding import Mesh, NamedSharding
 from jax.sharding import PartitionSpec as P
 
-from repro.compat import pcast_varying, shard_map
 from repro.core import patterns as _patterns
 from repro.core.graph import GraphEnsemble, TaskGraph
 from repro.core.runtimes import _halo
@@ -118,7 +118,7 @@ class _BspBase(Runtime):
                 x = jnp.broadcast_to(mean[None, :], local.shape)
                 # psum output is shard-invariant; re-mark as varying so scan
                 # carries keep a consistent VMA type under shard_map.
-                x = pcast_varying(x, AXIS)
+                x = jax.lax.pcast(x, AXIS, to="varying")
                 return apply_kernel(x, spec, use_pallas=use_pallas)
 
             return step

@@ -102,10 +102,10 @@ from typing import Callable, List, NamedTuple, Optional, Sequence, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import shard_map
 from jax.sharding import NamedSharding
 from jax.sharding import PartitionSpec as P
 
-from repro.compat import shard_map
 from repro.core import patterns as _patterns
 from repro.core.graph import GraphEnsemble, TaskGraph
 from repro.core.runtimes import _halo
@@ -629,6 +629,13 @@ class PallasStepRuntime(_BspBase):
                 f"unknown combine option {mode!r}: choose window, gather, "
                 f"or onehot ('pair' is the stride plan's internal "
                 f"lowering, selected automatically)")
+        if mode == "gather" and jax.default_backend() == "tpu":
+            # refused here, not deep in Mosaic, and never rewritten: an
+            # explicit ablation must run what it names or fail
+            raise ValueError(
+                "combine='gather' cannot run on the TPU: Mosaic has no row "
+                "gather. Use combine='onehot' (the same combine as an MXU "
+                "matmul) or the default window combine")
         return mode
 
     def _plan_combine(self, plan: str) -> str:
@@ -643,8 +650,8 @@ class PallasStepRuntime(_BspBase):
                      elementwise (a + b) * 0.5: gather-free, exact, and
                      Mosaic-friendly (slices and adds only). This is the
                      butterfly analogue of the halo plan's window mode.
-          allgather  "onehot" on TPU — the portable MXU lowering, since a
-                     Mosaic row gather may not lower (DESIGN.md §7) —
+          allgather  "onehot" on TPU — the MXU lowering, since a Mosaic
+                     row gather does not lower (DESIGN.md §4) —
                      and "gather" elsewhere, where fancy indexing lowers
                      fine and the onehot's (W, W) matrix build per step
                      is pure overhead.
@@ -1777,7 +1784,7 @@ class PallasStepRuntime(_BspBase):
             # launch shapes are membership-invariant (evict/admit only
             # edit mask/state VALUES) so this cache must never grow past
             # its first entry — the serving fabric asserts exactly that
-            compile_counter=getattr(launch, "_cache_size", None),
+            compile_counter=launch._cache_size,
         )
 
     def _launch_plan_stepwise(
@@ -1872,7 +1879,7 @@ class PallasStepRuntime(_BspBase):
                 rows=rows, steps_per_launch=1, model=model,
                 impl=self._halo_impl()),
             kind="stepwise",
-            compile_counter=getattr(step_jit, "_cache_size", None),
+            compile_counter=step_jit._cache_size,
         )
 
     # ----------------------------------------------------------- accounting

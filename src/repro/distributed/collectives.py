@@ -18,10 +18,10 @@ from typing import Optional, Sequence, Tuple, Union
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import Mesh
 from jax.sharding import PartitionSpec as P
 
-from repro.compat import shard_map
 from repro.kernels import ops
 
 AxisRef = Union[str, Tuple[str, ...]]

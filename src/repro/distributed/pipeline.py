@@ -18,10 +18,10 @@ from typing import Any, Callable
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import Mesh
 from jax.sharding import PartitionSpec as P
 
-from repro.compat import shard_map
 
 
 def pipeline_forward(

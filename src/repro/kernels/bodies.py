@@ -72,8 +72,15 @@ def memory_sweep_body(x: jax.Array, iterations: int, scratch: int) -> jax.Array:
     buf = jax.lax.fori_loop(0, iterations, body, buf)
     # reduce back to payload: mean over the scratch window per payload slot
     pad = reps * payload - scratch
-    buf = jnp.concatenate([buf, jnp.zeros(lead + (pad,), buf.dtype)], axis=-1)
-    return buf.reshape(lead + (reps, payload)).mean(axis=-2)
+    if pad:  # a zero-width operand does not lower on Mosaic
+        buf = jnp.concatenate([buf, jnp.zeros(lead + (pad,), buf.dtype)],
+                              axis=-1)
+    # a sum of static lane slices, not a (reps, payload) reshape: Mosaic
+    # cannot split the lane axis. Accumulated in f32, as jnp.mean does.
+    acc = buf[..., :payload].astype(jnp.float32)
+    for r in range(1, reps):
+        acc = acc + buf[..., r * payload:(r + 1) * payload].astype(jnp.float32)
+    return (acc / reps).astype(buf.dtype)
 
 
 def apply_body(x: jax.Array, kind: str, iterations: int, scratch: int) -> jax.Array:

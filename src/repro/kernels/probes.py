@@ -318,11 +318,9 @@ def _match_entry(entries: Dict[str, CostModel], platform: str,
 
 
 def _platform() -> str:
-    try:
-        import jax
-        return jax.default_backend()
-    except Exception:  # pragma: no cover - jax is a hard dep in practice
-        return "cpu"
+    import jax
+
+    return jax.default_backend()
 
 
 _default_cache: Dict[tuple, CostModel] = {}
@@ -501,9 +499,8 @@ def _sharded_wall_us(local_fn, devices: int, rows_per_device: int,
     measuring the transport it names."""
     import jax
     import jax.numpy as jnp
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
-
-    from repro.compat import shard_map
 
     mesh = _probe_mesh(devices)
     out_specs = P(None) if replicated_out else P(_AXIS)
@@ -783,7 +780,6 @@ def main(argv: Optional[Sequence[str]] = None) -> int:
             os.environ["XLA_FLAGS"] = flags.replace(
                 m.group(0),
                 f"--xla_force_host_platform_device_count={args.devices}")
-        os.environ.setdefault("JAX_PLATFORMS", "cpu")
 
     model = run_probes(devices=args.devices or None, payload=args.payload,
                        reps=args.reps, smoke=args.smoke)

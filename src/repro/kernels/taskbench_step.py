@@ -74,10 +74,10 @@ Three combine strategies, selected statically:
           idx is ignored (src row = own row + j by construction).
   gather  dependency rows are fancy-indexed out of src (lax.gather) per
           the idx operand — the general path for arbitrary padded dep
-          slots.
+          slots, in interpret mode only (a row gather does not lower on
+          Mosaic).
   onehot  the combine is lifted to a (W, S) one-hot weight matrix applied
-          with ``jnp.dot`` — the MXU-friendly fallback for TPUs where a
-          row gather does not lower.
+          with ``jnp.dot`` on the MXU — gather's form on the TPU.
   pair    for butterfly patterns (fft/tree): src carries [x | partner]
           halves stacked row-wise (S = 2*W; the runtime's stride plan
           builds the partner half with an XOR layout shuffle or a block
@@ -99,6 +99,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels.bodies import LANE, SUBLANE, apply_body
 
@@ -131,43 +132,58 @@ def _step_kernel(
     block_rows: int,
     pair_rows: int = 0,
 ):
-    src = src_ref[0]  # (S, Pp)
-    idx = idx_ref[0]  # (Wb, D)
     wgt = wgt_ref[0]  # (Wb, D)
+    n = wgt.shape[0]
 
+    # window/pair read their rows straight from the ref at a dynamic row
+    # offset (pl.ds): Mosaic lowers a ref load at any sublane offset, but
+    # not a dynamic_slice of a loaded value (DESIGN.md §4)
     if combine == "pair":
         # src = [x | partner] halves (second half starts at the TRUE
         # unpadded width pair_rows): the combine is elementwise
         # (a + b) * 0.5 — gather-free, and exact halving keeps it
         # bit-identical to the 2-dep masked mean.
         row0 = pl.program_id(1) * block_rows
-        srcf = src.astype(jnp.float32)
-        n = wgt.shape[0]
-        a = jax.lax.dynamic_slice_in_dim(srcf, row0, n, 0)
-        b = jax.lax.dynamic_slice_in_dim(srcf, pair_rows + row0, n, 0)
+        a = src_ref[0, pl.ds(row0, n), :].astype(jnp.float32)
+        b = src_ref[0, pl.ds(pair_rows + row0, n), :].astype(jnp.float32)
         x = (a + b) * jnp.float32(0.5)
     elif combine == "window":
         # wgt column j weighs the dependency at window offset j - halo:
         # out row w combines src rows [row0 + w .. row0 + w + 2*halo], a
         # static unrolled slice-FMA chain (no gather, no index arithmetic).
         row0 = pl.program_id(1) * block_rows
-        srcf = src.astype(jnp.float32)
-        x = jnp.zeros((wgt.shape[0], src.shape[1]), jnp.float32)
+        x = jnp.zeros((n, src_ref.shape[-1]), jnp.float32)
         for j in range(wgt.shape[1]):
-            win = jax.lax.dynamic_slice_in_dim(srcf, row0 + j, wgt.shape[0], 0)
-            x = x + win * wgt[:, j][:, None]
-    elif combine == "gather":
-        gathered = src[idx].astype(jnp.float32)  # (Wb, D, Pp)
+            win = src_ref[0, pl.ds(row0 + j, n), :].astype(jnp.float32)
+            x = x + win * wgt[:, j:j + 1]
+    elif combine == "gather":  # interpret mode only: no Mosaic row gather
+        idx = idx_ref[0]
+        gathered = src_ref[0][idx].astype(jnp.float32)  # (Wb, D, Pp)
         x = (gathered * wgt[..., None]).sum(axis=1)
     else:  # onehot: lift the gather to an MXU matmul
-        S = src.shape[0]
-        col = jax.lax.broadcasted_iota(jnp.int32, (1, 1, S), 2)
-        C = ((idx[..., None] == col).astype(jnp.float32) * wgt[..., None]).sum(axis=1)
-        x = jnp.dot(C, src.astype(jnp.float32), preferred_element_type=jnp.float32)
+        src = src_ref[0]
+        x = _onehot_dot(_onehot_matrix(idx_ref[0], wgt, src.shape[0]),
+                        src.astype(jnp.float32))
     o_ref[0] = _apply_body_padded(
-        x.astype(src.dtype), kind=kind, iterations=iterations,
+        x.astype(o_ref.dtype), kind=kind, iterations=iterations,
         scratch=scratch, payload=payload,
     )
+
+
+def _onehot_matrix(idx, wgt, cols: int):
+    """(rows, cols) combine matrix: row w holds wgt[w, j] at column
+    idx[w, j] (duplicate slots add up), so ``C @ src`` is the weighted
+    gather."""
+    col = jax.lax.broadcasted_iota(jnp.int32, (1, 1, cols), 2)
+    return ((idx[..., None] == col).astype(jnp.float32)
+            * wgt[..., None]).sum(axis=1)
+
+
+def _onehot_dot(C, srcf):
+    """``C @ srcf`` at full f32 precision: the MXU's default single
+    bf16 pass would round the state and the 1/live-count weights."""
+    return jnp.dot(C, srcf, precision=jax.lax.Precision.HIGHEST,
+                   preferred_element_type=jnp.float32)
 
 
 def _apply_body_padded(x, *, kind, iterations, scratch, payload):
@@ -209,9 +225,14 @@ def _blocked_step_kernel(
     depth axis — one table per inner step — so patterns whose dependence
     sets change with t (butterfly strides, spread's rotation) can run
     blocked: depth d applies table d. The act-mask freezing is unchanged.
+
+    Every index that varies with the depth ``d`` lands on a ref, never on
+    a loaded value: the (K, S) act mask lives whole in SMEM (read as the
+    scalar ``act_ref[k, d]``) and depth tables are indexed on their
+    leading ref axis, the two forms Mosaic lowers (DESIGN.md §4).
     """
+    k = pl.program_id(0)
     buf0 = src_ref[0]  # (Mp, Pp) working state, full size at every depth
-    act = act_ref[0]  # (S,) 1.0 = this inner step executes
     M = buf0.shape[0]
     if not time_varying:
         wgt = wgt_ref[0]  # (Mp, D) per-row weights, fixed across depths
@@ -221,24 +242,18 @@ def _blocked_step_kernel(
         if combine == "onehot":
             # idx/wgt are depth-invariant, so the (M, M) one-hot combine
             # matrix is built ONCE per launch, not once per inner step
-            idx = idx_ref[0]
-            col = jax.lax.broadcasted_iota(jnp.int32, (1, 1, M), 2)
-            onehot_C = ((idx[..., None] == col).astype(jnp.float32)
-                        * wgt[..., None]).sum(axis=1)
+            onehot_C = _onehot_matrix(idx_ref[0], wgt, M)
 
     def depth_step(d, buf):
         srcf = buf.astype(jnp.float32)
         if time_varying:
             # (S, Mp, D) tables: depth d combines with table d
-            ti = jax.lax.dynamic_index_in_dim(idx_ref[0], d, 0, keepdims=False)
-            tw = jax.lax.dynamic_index_in_dim(wgt_ref[0], d, 0, keepdims=False)
+            ti = idx_ref[0, d]
+            tw = wgt_ref[0, d]
             if combine == "gather":
                 x = (srcf[ti] * tw[..., None]).sum(axis=1)
             else:  # onehot, built per depth (the matrix changes with d)
-                col = jax.lax.broadcasted_iota(jnp.int32, (1, 1, M), 2)
-                C = ((ti[..., None] == col).astype(jnp.float32)
-                     * tw[..., None]).sum(axis=1)
-                x = jnp.dot(C, srcf, preferred_element_type=jnp.float32)
+                x = _onehot_dot(_onehot_matrix(ti, tw, M), srcf)
         elif combine == "window":
             # out row i combines work rows [i .. i + 2*halo] of the +-halo
             # zero-padded buffer: same static slice-FMA chain as the
@@ -247,21 +262,20 @@ def _blocked_step_kernel(
             work = jnp.concatenate([zpad, srcf, zpad], axis=0)
             x = jnp.zeros((M, srcf.shape[1]), jnp.float32)
             for j in range(wgt.shape[1]):
-                win = jax.lax.dynamic_slice_in_dim(work, j, M, 0)
-                x = x + win * wgt[:, j][:, None]
+                x = x + work[j:j + M] * wgt[:, j:j + 1]
         elif combine == "gather":
             idx = idx_ref[0]  # (Mp, D) absolute rows of THIS buffer
             gathered = srcf[idx]  # (Mp, D, Pp)
             x = (gathered * wgt[..., None]).sum(axis=1)
         else:  # onehot: lift the self-gather to an MXU matmul
-            x = jnp.dot(onehot_C, srcf, preferred_element_type=jnp.float32)
+            x = _onehot_dot(onehot_C, srcf)
         x = _apply_body_padded(
             x.astype(buf.dtype), kind=kind, iterations=iterations,
             scratch=scratch, payload=payload,
         )
         # masked freeze: inactive depths (a frozen ensemble member, or the
         # tail of the final partial launch) carry the buffer through intact
-        return jnp.where(act[d] > 0.5, x, buf)
+        return jnp.where(act_ref[k, d] > 0.5, x, buf)
 
     # ROLLED loop over depths (the buffer is full-size at every depth
     # precisely so the carry shape is loop-invariant): a rolled loop
@@ -349,7 +363,10 @@ def _blocked_call(src, idx, wgt, act, *, kind, iterations, scratch,
             pl.BlockSpec((1, Mp, Pp), lambda k: (k, 0, 0)),
             idx_block,
             wgt_block,
-            pl.BlockSpec((1, S), lambda k: (k, 0)),
+            # whole (K, S) mask as SMEM scalars: a (1, S) VMEM block of it
+            # breaks the (8, 128) tiling rule, and a depth-indexed read of
+            # a vector value does not lower
+            pl.BlockSpec(memory_space=pltpu.SMEM),
         ],
         out_specs=pl.BlockSpec((1, Mp, Pp), lambda k: (k, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((K, Mp, Pp), src.dtype),
@@ -394,6 +411,11 @@ def taskbench_step_pallas(
     """
     if combine not in COMBINE_MODES:
         raise ValueError(f"unknown combine mode {combine!r}; known {COMBINE_MODES}")
+    if combine == "gather" and not interpret:
+        # a row gather (src[idx] on a value) has no Mosaic lowering
+        raise ValueError(
+            "combine='gather' does not lower for the TPU (Mosaic has no row "
+            "gather); use combine='onehot', the MXU form of the same combine")
     if src.ndim != 3 or wgt.ndim not in (3, 4):
         raise ValueError(
             f"expected (K, S, payload)/(K, W, D) operands, got "

@@ -13,28 +13,12 @@ train_step keeps that hop to 1/16 of the gradient bytes.
 from __future__ import annotations
 
 import jax
-from jax.sharding import Mesh
-
-# jax.sharding.AxisType (and make_mesh's axis_types kwarg) only exist on
-# newer jax; on 0.4.x every mesh axis is implicitly Auto, which is exactly
-# what we request on newer versions — so omitting the kwarg is equivalent.
-try:  # jax >= 0.5
-    import inspect
-
-    from jax.sharding import AxisType
-
-    _AXIS_TYPE_KW = "axis_types" in inspect.signature(jax.make_mesh).parameters
-except ImportError:  # jax 0.4.x
-    AxisType = None
-    _AXIS_TYPE_KW = False
+from jax.sharding import AxisType, Mesh
 
 
 def _make_mesh(shape, axes) -> Mesh:
-    """`jax.make_mesh` with Auto axis types on every jax version."""
-    if _AXIS_TYPE_KW:
-        return jax.make_mesh(shape, axes,
-                             axis_types=(AxisType.Auto,) * len(axes))
-    return jax.make_mesh(shape, axes)
+    """`jax.make_mesh` with every axis Auto (GSPMD-propagated)."""
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False) -> Mesh:

@@ -2,15 +2,8 @@
 see the real single-CPU device set (the 512-device forcing belongs ONLY to
 launch/dryrun.py). Tests that need multi-device meshes spawn subprocesses
 (see test_distributed.py) or use what `jax.devices()` offers.
-
-`hypothesis` is an OPTIONAL test dependency (declared in pyproject's `test`
-extra). When it is absent we install a stub into sys.modules so every test
-module still collects; tests decorated with the stub's @given skip with a
-clear reason instead of killing collection for the whole module.
 """
 import os
-import sys
-import types
 
 import pytest
 
@@ -22,68 +15,6 @@ os.environ.setdefault("JAX_PLATFORMS", "cpu")
 # flip them. "off" pins the analytic fallback; cost-model tests that need a
 # cache point REPRO_COST_MODEL at a tmp_path file via monkeypatch.
 os.environ.setdefault("REPRO_COST_MODEL", "off")
-
-
-def _install_hypothesis_stub() -> None:
-    mod = types.ModuleType("hypothesis")
-    strategies = types.ModuleType("hypothesis.strategies")
-
-    class _Strategy:
-        """Opaque placeholder accepted anywhere a SearchStrategy goes."""
-
-        def __init__(self, *args, **kwargs):
-            pass
-
-        def map(self, *a, **k):
-            return self
-
-        def filter(self, *a, **k):
-            return self
-
-        def flatmap(self, *a, **k):
-            return self
-
-    def _make_strategy(*args, **kwargs):
-        return _Strategy()
-
-    for name in ("integers", "floats", "booleans", "text", "sampled_from",
-                 "lists", "tuples", "just", "one_of", "none", "composite",
-                 "dictionaries", "sets", "builds", "binary"):
-        setattr(strategies, name, _make_strategy)
-
-    def given(*_args, **_kwargs):
-        def decorate(fn):
-            def skipper():
-                pytest.skip("hypothesis not installed "
-                            "(pip install '.[test]' to run property tests)")
-
-            skipper.__name__ = fn.__name__
-            skipper.__doc__ = fn.__doc__
-            skipper.__module__ = fn.__module__
-            return skipper
-
-        return decorate
-
-    def settings(*_args, **_kwargs):
-        def decorate(fn):
-            return fn
-
-        return decorate
-
-    mod.given = given
-    mod.settings = settings
-    mod.strategies = strategies
-    mod.assume = lambda condition: bool(condition)
-    mod.example = settings  # decorator-compatible no-op
-    mod.HealthCheck = types.SimpleNamespace(all=lambda: [])
-    sys.modules["hypothesis"] = mod
-    sys.modules["hypothesis.strategies"] = strategies
-
-
-try:
-    import hypothesis  # noqa: F401
-except ImportError:
-    _install_hypothesis_stub()
 
 
 @pytest.fixture(scope="session")

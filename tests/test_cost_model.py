@@ -422,3 +422,16 @@ def test_run_probes_smoke_includes_gather_impl_table():
     single device it stays empty (nothing to rendezvous)."""
     m = probes.run_probes(devices=1, smoke=True, reps=1)
     assert m.gather_impl_us == {}
+
+
+def test_platform_lookup_failure_propagates(monkeypatch):
+    """No silent "cpu" when the backend lookup fails: a calibration keyed
+    to the wrong platform would steer every schedule on the real one."""
+    import jax
+
+    def no_backend():
+        raise RuntimeError("backend init failed")
+
+    monkeypatch.setattr(jax, "default_backend", no_backend)
+    with pytest.raises(RuntimeError, match="backend init failed"):
+        probes._platform()

@@ -84,7 +84,7 @@ def test_halo_async_exchange_parity_multi_device():
     run_sub("""
         import numpy as np, jax, jax.numpy as jnp
         from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-        from repro.compat import shard_map
+        from jax import shard_map
         from repro.core.runtimes import _halo
 
         D, B, Pay = 4, 6, 5
@@ -140,7 +140,7 @@ def test_stride_exchange_oracle_multi_device():
     run_sub("""
         import numpy as np, jax, jax.numpy as jnp
         from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-        from repro.compat import shard_map
+        from jax import shard_map
         from repro.core.runtimes import _halo
 
         D, B, Pay = 4, 5, 3
@@ -489,7 +489,7 @@ def test_grad_compression_int8_cross_pod():
     run_sub("""
         import jax, numpy as np, jax.numpy as jnp
         from jax.sharding import PartitionSpec as P
-        from repro.compat import shard_map
+        from jax import shard_map
         from repro.optim.grad_compression import cross_pod_mean_int8
         from repro.launch.mesh import make_host_mesh
 
@@ -609,7 +609,7 @@ def test_gather_transports_match_monolithic_oracle_16_devices():
         import jax
         import jax.numpy as jnp
         from jax.sharding import Mesh, PartitionSpec as P
-        from repro.compat import shard_map
+        from jax import shard_map
         from repro.core.runtimes import _halo
 
         D = 16

@@ -150,6 +150,20 @@ def test_taskbench_step_window_matches_gather():
                                rtol=1e-6, atol=1e-7)
 
 
+@pytest.mark.parametrize("steps_per_launch", [1, 3])
+def test_taskbench_step_gather_needs_interpret_mode(steps_per_launch):
+    """A row gather has no Mosaic lowering: asked for on the chip path
+    (interpret=False) it fails up front and names onehot."""
+    K, S, W, P, D = 1, 12, 12, 8, 3
+    src = jnp.ones((K, S, P))
+    idx, wgt = _random_step_operands(23, K, S, W, D)
+    act = jnp.ones((K, steps_per_launch)) if steps_per_launch > 1 else None
+    with pytest.raises(ValueError, match="onehot"):
+        taskbench_step_pallas(src, idx, wgt, act, combine="gather",
+                              steps_per_launch=steps_per_launch,
+                              interpret=False)
+
+
 def test_taskbench_step_block_rows_invariance():
     K, S, W, P, D = 1, 32, 32, 16, 3
     src = jax.random.uniform(jax.random.PRNGKey(21), (K, S, P),

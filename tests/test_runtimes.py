@@ -398,6 +398,23 @@ def test_pallas_step_plan_dispatch_and_rejection_message():
                 graph("stencil_1d"))
 
 
+@pytest.mark.parametrize("pattern", ["stencil_1d", "spread"])
+def test_pallas_step_gather_refused_on_tpu(pattern, monkeypatch):
+    """An explicit gather combine on the TPU fails at plan resolution with
+    a message naming onehot: never deep in Mosaic, never rewritten."""
+    import jax
+
+    rt = get_runtime("pallas_step", combine="gather")
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    with pytest.raises(ValueError, match="onehot"):
+        rt.build(graph(pattern))
+    monkeypatch.undo()
+    # off the TPU the gather ablation still runs (interpret mode)
+    np.testing.assert_allclose(rt.execute(graph(pattern)),
+                               get_runtime("fused").execute(graph(pattern)),
+                               rtol=1e-6, atol=1e-6)
+
+
 BUTTERFLY = list(_patterns.BUTTERFLY_PATTERNS)
 
 

@@ -116,7 +116,7 @@ def test_fabric_on_four_devices():
 
         # forced chunk groupings are bit-identical to the monolithic path
         from jax.sharding import Mesh, PartitionSpec as P
-        from repro.compat import shard_map
+        from jax import shard_map
         mesh = Mesh(np.array(devs), ("shard",))
         x = jnp.arange(8 * 3, dtype=jnp.float32).reshape(8, 3)
         ref = np.asarray(x)

@@ -1,0 +1,145 @@
+"""The Task Bench kernels compile for a TPU v5e chip at real widths.
+
+Nothing runs: each test lowers one kernel entry for a chip of a described
+``v5e:2x2`` topology and asserts that Mosaic accepted it (the compiled
+module holds a ``tpu_custom_call``). This guards the forms the chip's
+compiler takes (DESIGN.md §4) with no chip attached. The topology is
+described inside a fixture, never at import, so every test worker collects
+the same tests and only the worker given this file loads the TPU library.
+"""
+import functools
+import os
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from repro.kernels.bodies import memory_bound_pallas
+from repro.kernels.schedule import DEFAULT_GATHER_WIDTH_CAP
+from repro.kernels.taskbench_compute import taskbench_compute_pallas
+from repro.kernels.taskbench_step import (
+    taskbench_step_boundary,
+    taskbench_step_interior,
+    taskbench_step_pallas,
+)
+
+W = 4096  # the chip smoke's width
+PAYLOAD = 64
+BODY = dict(kind="compute_bound", iterations=64, scratch=2048,
+            interpret=False)
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(scope="module")
+def no_compile_cache():
+    """A compile for a described chip is written to the persistent cache
+    but cannot be read back without the chip: keep the cache off."""
+    from jax.experimental.compilation_cache import compilation_cache
+
+    before = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", before)
+
+
+def _compile(fn, *shapes):
+    return jax.jit(fn).lower(*shapes).compile().as_text()
+
+
+def _sds(sharding, shape, dtype=jnp.float32):
+    return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+
+def _window(K, M, depth_masks=0):
+    """(src, idx, wgt[, act]) shapes of a radius-1 window launch."""
+    shapes = [((K, M + (0 if depth_masks else 2), PAYLOAD), jnp.float32),
+              ((K, 1, 1), jnp.int32), ((K, M, 3), jnp.float32)]
+    if depth_masks:
+        shapes.append(((K, depth_masks), jnp.float32))
+    return shapes
+
+
+def _onehot(K, width, depths, D=3):
+    lead = (K, depths) if depths > 1 else (K,)
+    shapes = [((K, width, PAYLOAD), jnp.float32),
+              (lead + (width, D), jnp.int32), (lead + (width, D), jnp.float32)]
+    if depths > 1:
+        shapes.append(((K, depths), jnp.float32))
+    return shapes
+
+
+# name -> (callable, shapes); S>1 entries take the act mask positionally
+CASES = {
+    "window_s1": (functools.partial(taskbench_step_pallas, combine="window",
+                                    **BODY),
+                  _window(1, W)),
+    "window_blocked_k4_s8": (
+        lambda s, i, w, a: taskbench_step_pallas(
+            s, i, w, a, combine="window", steps_per_launch=8, **BODY),
+        _window(4, 1024 + 16, depth_masks=8)),
+    # the widest blocked launch that fits VMEM (6144 + 16 rows does not)
+    "window_blocked_widest": (
+        lambda s, i, w, a: taskbench_step_pallas(
+            s, i, w, a, combine="window", steps_per_launch=8, **BODY),
+        _window(1, 5120 + 16, depth_masks=8)),
+    "pair": (functools.partial(taskbench_step_pallas, combine="pair", **BODY),
+             [((1, 2 * W, PAYLOAD), jnp.float32), ((1, 1, 1), jnp.int32),
+              ((1, W, 1), jnp.float32)]),
+    "interior_phase": (
+        lambda s, i, w, a: taskbench_step_interior(
+            s, i, w, a, depth=8, combine="window", steps_per_launch=8,
+            **BODY),
+        [((1, W, PAYLOAD), jnp.float32), ((1, 1, 1), jnp.int32),
+         ((1, W, 3), jnp.float32), ((1, 8), jnp.float32)]),
+    "boundary_phase": (
+        lambda l, r, i, w, a: taskbench_step_boundary(
+            l, r, i, w, a, depth=8, combine="window", steps_per_launch=8,
+            **BODY),
+        [((1, 24, PAYLOAD), jnp.float32), ((1, 24, PAYLOAD), jnp.float32),
+         ((1, 1, 1), jnp.int32), ((1, 48, 3), jnp.float32),
+         ((1, 8), jnp.float32)]),
+    # the all-gather cap; 768 rows still fit VMEM, 896 do not
+    "onehot_s1_cap": (
+        functools.partial(taskbench_step_pallas, combine="onehot", **BODY),
+        _onehot(1, DEFAULT_GATHER_WIDTH_CAP, 1)),
+    "onehot_time_varying_s4_cap": (
+        lambda s, i, w, a: taskbench_step_pallas(
+            s, i, w, a, combine="onehot", steps_per_launch=4, **BODY),
+        _onehot(1, DEFAULT_GATHER_WIDTH_CAP, 4)),
+    "memory_bound_megakernel": (
+        functools.partial(taskbench_step_pallas, combine="window",
+                          block_rows=256, **dict(BODY, kind="memory_bound",
+                                                 iterations=16)),
+        _window(1, W)),
+    "compute_bound_body": (
+        functools.partial(taskbench_compute_pallas, iterations=64),
+        [((W, PAYLOAD), jnp.float32)]),
+    "memory_bound_body": (
+        functools.partial(memory_bound_pallas, iterations=16, scratch=2048),
+        [((W, PAYLOAD), jnp.float32)]),
+}
+
+
+@pytest.mark.parametrize("name", sorted(CASES))
+def test_kernel_compiles_for_v5e(name, one_chip, no_compile_cache):
+    fn, shapes = CASES[name]
+    text = _compile(fn, *(_sds(one_chip, s, d) for s, d in shapes))
+    assert "tpu_custom_call" in text, f"{name}: no Mosaic kernel in module"
