@@ -167,11 +167,14 @@ def _trace_row(rt, graph, spec: SweepSpec, name: str, vlabel: str,
                grain: int) -> Dict:
     """One traced execution -> the row's decomposition summary (and,
     with ``trace_dir``, a Chrome trace file). Runs AFTER the timed reps so
-    the probe/warmup cost of tracing can never leak into the walls."""
+    the warmup cost of tracing can never leak into the walls; the spans the
+    timed reps left in the tracer are dropped first, so the summary covers
+    this one execution."""
     import re
 
     from repro.obs import summarize, write_chrome_trace
 
+    rt.tracer.clear()
     rt.trace_once(graph)
     summary = summarize(rt.tracer.spans)
     if spec.trace_dir:

@@ -38,11 +38,8 @@ and finite — so a broken calibration fails CI before it silently steers
 every "auto" schedule; a missing file SKIPs (local runs stay green).
 
 The optional ``--trace`` file (written by benchmarks/overhead_decomposition)
-arms the TRACE-FED health leg: instead of re-deriving an overlap signal
-from walls, the span trace's own verdict (hidden exchange fraction) and
-exchange share of wall are judged directly — ``--smoke`` points it at the
-smoke artifact with a presence/sanity bound (tiny smoke shapes cannot
-hide their exchange; full runs default to requiring >50% hidden).
+arms the trace leg: a schema sanity check over the decomposition artifact
+(``--smoke`` points it at the smoke artifact).
 
 Exit status: 1 iff any check FAILs. Checks found in only one artifact are
 reported and SKIPped, never judged.
@@ -230,42 +227,15 @@ def cost_model_checks(model_file: dict) -> List[PerfCheck]:
     return checks
 
 
-def trace_checks(trace_art: dict, *, max_visible: float,
-                 max_exchange_fraction: float) -> List[PerfCheck]:
-    """Trace-fed health leg over the overhead_decomposition artifact.
-
-    Replaces a re-derived signal with what the span trace DIRECTLY
-    measured: ``trace@schema`` is the sanity half (artifact schema, a
-    well-formed overlap verdict), ``trace@overlap`` the perf half — the
-    VISIBLE exchange fraction (1 - hidden_fraction) judged against an
-    ideal reference of full hiding, with the run's own exchange share of
-    total wall as the health signal. The same two-signal rule as every
-    other check: a shortfall in hiding only FAILs when exchange also
-    dominates the wall (the pipeline broke AND it matters); a shortfall
-    over a wall that exchange barely touches stays a WARN."""
+def trace_checks(trace_art: dict) -> List[PerfCheck]:
+    """Trace leg over the overhead_decomposition artifact: ``trace@schema``
+    checks the artifact's schema."""
     errors: List[str] = []
     if trace_art.get("schema") != 1:
         errors.append(
             f"trace artifact schema {trace_art.get('schema')!r}, expected 1")
-    ov = trace_art.get("pallas_overlap") or {}
-    verdict = ov.get("verdict")
-    if verdict not in ("hidden", "visible", "unavailable", None):
-        errors.append(f"unknown overlap verdict {verdict!r}")
-    hidden = ov.get("hidden_fraction")
-    if hidden is not None and not (0.0 <= float(hidden) <= 1.0):
-        errors.append(f"hidden_fraction out of [0, 1]: {hidden!r}")
-    checks = [PerfCheck(name="trace@schema", value=None, reference=None,
-                        factor=1.0, sanity_errors=errors)]
-    visible = None if hidden is None else max(1.0 - float(hidden), 1e-9)
-    checks.append(PerfCheck(
-        name="trace@overlap", value=visible, reference=1.0,
-        factor=max_visible,
-        fmt=lambda v: f"{v * 100:.0f}% exchange visible",
-        health_desc="exchange_fraction",
-        health_value=trace_art.get("pallas_exchange_fraction"),
-        health_bad=lambda f, hi=max_exchange_fraction: f > hi,
-    ))
-    return checks
+    return [PerfCheck(name="trace@schema", value=None, reference=None,
+                      factor=1.0, sanity_errors=errors)]
 
 
 def chaos_checks(chaos_art: dict, *, max_recovery_tax: float,
@@ -469,8 +439,6 @@ def build_suite(current: dict, baseline: dict, factor: float,
                 min_amortization: float,
                 cost_model: Optional[dict] = None,
                 trace_art: Optional[dict] = None,
-                max_visible: float = 1.0,
-                max_exchange_fraction: float = 0.6,
                 chaos_art: Optional[dict] = None,
                 max_recovery_tax: float = 2.5,
                 max_armor_tax: float = 3.0,
@@ -486,8 +454,7 @@ def build_suite(current: dict, baseline: dict, factor: float,
     if cost_model is not None:
         checks += cost_model_checks(cost_model)
     if trace_art is not None:
-        checks += trace_checks(trace_art, max_visible=max_visible,
-                               max_exchange_fraction=max_exchange_fraction)
+        checks += trace_checks(trace_art)
     if chaos_art is not None:
         checks += chaos_checks(chaos_art, max_recovery_tax=max_recovery_tax,
                                max_armor_tax=max_armor_tax)
@@ -533,8 +500,6 @@ def check(current: dict, baseline: dict, factor: float,
           min_amortization: float,
           cost_model: Optional[dict] = None,
           trace_art: Optional[dict] = None,
-          max_visible: float = 1.0,
-          max_exchange_fraction: float = 0.6,
           chaos_art: Optional[dict] = None,
           max_recovery_tax: float = 2.5,
           max_armor_tax: float = 3.0,
@@ -565,8 +530,7 @@ def check(current: dict, baseline: dict, factor: float,
         # the committed baseline predates a new K sweep
         families["serve@"] = 3
     suite = build_suite(current, baseline, factor, min_amortization,
-                        cost_model, trace_art, max_visible,
-                        max_exchange_fraction, chaos_art,
+                        cost_model, trace_art, chaos_art,
                         max_recovery_tax, max_armor_tax,
                         scaling_art, scaling_base,
                         max_pallas_over_bsp, min_gather_speedup,
@@ -594,16 +558,7 @@ def main(argv=None):
                          "trace health leg (missing file = skip)")
     ap.add_argument("--smoke", action="store_true",
                     help="smoke defaults: --trace points at the smoke "
-                         "decomposition artifact, and the overlap bound "
-                         "relaxes to presence/sanity only (tiny smoke "
-                         "shapes cannot hide their exchange)")
-    ap.add_argument("--max-visible", type=float, default=None,
-                    help="visible exchange fraction above which the "
-                         "overlap check regresses (default 0.5, i.e. the "
-                         "pipeline must hide >50%%; 1.0 under --smoke)")
-    ap.add_argument("--max-exchange-fraction", type=float, default=0.6,
-                    help="in-run health bound: exchange share of total "
-                         "wall above which an overlap shortfall FAILs")
+                         "decomposition artifact")
     ap.add_argument("--chaos", default=None, nargs="?",
                     const="artifacts/bench/chaos.json",
                     help="benchmarks/chaos artifact feeding the resilience "
@@ -651,9 +606,6 @@ def main(argv=None):
     trace_path = a.trace
     if trace_path is None and a.smoke:
         trace_path = "artifacts/bench/overhead_decomposition_smoke.json"
-    max_visible = a.max_visible
-    if max_visible is None:
-        max_visible = 1.0 if a.smoke else 0.5
     with open(a.current) as f:
         current = json.load(f)
     with open(a.baseline) as f:
@@ -716,8 +668,7 @@ def main(argv=None):
                 print(f"floor_guard: serve baseline {a.serve_baseline} "
                       f"absent (serve@p99 judged only via overrides)")
     failures = check(current, baseline, a.factor, a.min_amortization,
-                     cost_model, trace_art, max_visible,
-                     a.max_exchange_fraction, chaos_art,
+                     cost_model, trace_art, chaos_art,
                      a.max_recovery_tax, a.max_armor_tax,
                      scaling_art, scaling_base,
                      a.max_pallas_over_bsp, a.min_gather_speedup,

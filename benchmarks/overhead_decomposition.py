@@ -7,26 +7,17 @@ tracer: every backend runs every pattern with ``trace=`` on, and each
 row's wall is attributed to dispatch / exchange / gather / compute / idle
 by interval arithmetic over the recorded spans (repro.obs.decompose).
 
-Two headline artifacts per row ride along:
+Each row carries the stacked per-category breakdown (the figure's bars)
+— e.g. `serialized` should be dispatch-dominated at fine grain while
+`bsp_scan`/`fused` collapse everything into one dispatch. pallas_step runs
+one scanned program per graph, so its row splits only host dispatch from
+the device drain; per-op device time comes from a profiler trace
+(DESIGN.md §10).
 
-  * the stacked per-category breakdown (the figure's bars) — e.g.
-    `serialized` should be dispatch-dominated at fine grain while
-    `bsp_scan`/`fused` collapse everything into one dispatch;
-  * for the pipelined pallas_step row, the OVERLAP VERDICT: phase probes
-    price what the boundary / exchange / interior phases cost standalone,
-    and the combined launch walls then reveal how much exchange time the
-    interior actually absorbed (hidden_fraction > 0.5 = the deep-halo
-    pipeline is doing its job; the verdict documents the measured value
-    either way).
-
-Full mode (default): 4 devices, width 512, tuned ("auto") launch depth —
-the configuration PR 4 showed covers the exchange; the verdict is judged
-from a dedicated grain=1 row (the METG regime — at the table's coarse
-grain the exchange is smaller than probe jitter and the split cannot
-resolve it). Smoke mode: 2 devices,
-width 64, explicit steps_per_launch=4 (the analytic covering rule
-declines tiny shapes, so smoke FORCES the pipelined path to keep the
-verdict machinery exercised in CI).
+Full mode (default): 4 devices, width 512, tuned ("auto") launch depth.
+Smoke mode: 2 devices, width 64, explicit steps_per_launch=4 (the
+analytic covering rule declines tiny shapes, so smoke FORCES the
+pipelined path).
 
 Chrome traces for every row land in artifacts/bench/traces/ (load in
 chrome://tracing or ui.perfetto.dev).
@@ -67,19 +58,13 @@ def _trace_cell(row: dict) -> dict:
         "wall_us": tr.get("wall_us"),
         "fractions": tr.get("fractions"),
         "categories_us": tr.get("categories_us"),
-        "overlap": tr.get("overlap"),
         "decisions": tr.get("decisions"),
     }
 
 
-def _exchange_fraction(cell: dict) -> float:
-    fr = cell.get("fractions") or {}
-    return float(fr.get("exchange", 0.0)) + float(fr.get("gather", 0.0))
-
-
 def run(devices: int, width: int, steps: int, grain: int, *,
         pallas_options: dict, options: dict, trace_dir: str,
-        verdict_grain: int = 0, timeout: int = 3000) -> dict:
+        timeout: int = 3000) -> dict:
     decomposition: dict = {}
     for pattern in PATTERNS:
         cells: dict = {}
@@ -116,23 +101,6 @@ def run(devices: int, width: int, steps: int, grain: int, *,
         extra[pattern] = (
             {"skip": rows[0]["skip"]} if "skip" in rows[0] else
             _trace_cell(rows[0]))
-    pallas = decomposition["stencil_1d"].get("pallas_step", {})
-    # the overlap VERDICT row: at coarse grain the exchange is a
-    # vanishing fraction of the launch wall (probe jitter alone exceeds
-    # it), so full mode re-runs the pipelined stencil row at a FINE grain
-    # (the paper's METG regime) where exchange is a real fraction and
-    # hidden-vs-visible is resolvable. 0 = judge from the table row
-    # (smoke: the forced-S row already is the fine-grain regime).
-    verdict_cell = pallas
-    if verdict_grain and verdict_grain != grain:
-        vrows = run_worker(SweepSpec(
-            runtime="pallas_step", pattern="stencil_1d", devices=devices,
-            width=width, steps=steps, grains=(verdict_grain,),
-            options={**options, **pallas_options},
-            trace=True, trace_dir=trace_dir,
-        ), timeout=timeout)
-        if "skip" not in vrows[0]:
-            verdict_cell = _trace_cell(vrows[0])
     return {
         "schema": 1,
         "devices": devices,
@@ -142,11 +110,6 @@ def run(devices: int, width: int, steps: int, grain: int, *,
         "pallas_options": pallas_options,
         "decomposition": decomposition,
         "extra_plans": extra,
-        "verdict_grain": verdict_grain or grain,
-        "verdict_row": verdict_cell,
-        # the two headline signals floor_guard's trace leg consumes
-        "pallas_overlap": verdict_cell.get("overlap"),
-        "pallas_exchange_fraction": _exchange_fraction(verdict_cell),
     }
 
 
@@ -168,27 +131,6 @@ def print_report(art: dict) -> None:
             bars = "".join(
                 f"{100 * float(fr.get(c, 0.0)):>9.1f}%" for c in CATEGORIES)
             print(f"{name:12s}{bars}{1e3 * cell['wall']:>10.2f}")
-    ov = art.get("pallas_overlap")
-    if ov and ov.get("verdict") in ("hidden", "visible"):
-        print(f"\noverlap verdict (pipelined pallas_step, stencil_1d, "
-              f"grain={art.get('verdict_grain', art['grain'])}): "
-              f"{ov['verdict'].upper()} — {100 * ov['hidden_fraction']:.0f}% "
-              f"of exchange wall hidden under interior compute "
-              f"({ov['launches']} launches, exchange "
-              f"{ov['exchange_per_launch_us']:.1f} us/launch, combined "
-              f"launch {ov['combined_launch_us']:.1f} us)")
-        if ov["verdict"] == "visible":
-            print("  (on this container every forced host device "
-                  "multiplexes ONE physical core, so exchange and interior "
-                  "compute cannot truly run concurrently — the pipeline's "
-                  "measured wins come from fewer dispatch sync points and "
-                  "the fused collective, and the verdict machinery is what "
-                  "real multi-core/TPU runs will read)")
-    elif ov:
-        print(f"\noverlap verdict: {ov.get('verdict')} "
-              f"({ov.get('reason', '')})")
-    else:
-        print("\noverlap verdict: none (pallas_step row did not pipeline)")
 
 
 def main(argv=None) -> int:
@@ -210,9 +152,8 @@ def main(argv=None) -> int:
         steps = args.steps or 9
         grain = args.grain or 64
         # the analytic covering rule declines tiny blocks; force the
-        # pipelined path so CI still exercises the verdict machinery
+        # pipelined path so CI still exercises it
         pallas_options = {"steps_per_launch": 4}
-        verdict_grain = 0  # the smoke table row already is fine-grain
         out = args.out or bench_path("overhead_decomposition_smoke.json")
     else:
         devices = args.devices or 4
@@ -220,12 +161,10 @@ def main(argv=None) -> int:
         steps = args.steps or 33
         grain = args.grain or 1024
         pallas_options = {"steps_per_launch": "auto"}
-        verdict_grain = 1  # the METG regime: exchange a real fraction
         out = args.out or bench_path("overhead_decomposition.json")
 
     art = run(devices, width, steps, grain, pallas_options=pallas_options,
-              options=options, trace_dir=bench_path("traces"),
-              verdict_grain=verdict_grain)
+              options=options, trace_dir=bench_path("traces"))
     art["mode"] = "smoke" if args.smoke else "full"
     with open(out, "w") as f:
         json.dump(art, f, indent=1)

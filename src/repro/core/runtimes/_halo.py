@@ -115,21 +115,6 @@ def ring_perms(num_devices: int, axis: str = "shard"):
     return fwd, bwd
 
 
-def transport_span(tracer, kind: str, *, impl: str, depth: int = 0, **attrs):
-    """The one span every traced transport dispatch goes through.
-
-    Centralizing the category choice and the ``impl``/``depth`` tagging here
-    keeps the attribution uniform across all three transport families (ring
-    halo, stride/XOR partner, global gather) no matter which runtime issues
-    them — decompose.py can then split "exchange" from "gather" wall without
-    knowing which backend produced the trace. ``kind`` is the span name
-    (e.g. "deep_exchange", "stride_exchange", "gather_global"); gather-family
-    kinds land in the ``gather`` category, everything else in ``exchange``.
-    """
-    category = "gather" if "gather" in kind else "exchange"
-    return tracer.span(kind, category, impl=impl, depth=depth, **attrs)
-
-
 @dataclasses.dataclass(frozen=True)
 class HaloHandle:
     """An in-flight ring exchange: the double-buffered halo slots.
@@ -595,3 +580,15 @@ def exchange_halos(local: jax.Array, r: int, num_devices: int,
         exchange_halos_start(local, r, num_devices, axis, row_axis=row_axis,
                              impl="ppermute")
     )
+
+
+def ring_extend(local: jax.Array, r: int, num_devices: int,
+                axis: str = "shard", *, row_axis: int = 0) -> jax.Array:
+    """``local`` extended by its r ring neighbours' rows on each side,
+    [recv_left | local | recv_right] along ``row_axis`` (``exchange_halos``
+    then one concatenate), under the ``halo_extend`` named scope so the
+    ops keep that name in a profile."""
+    with jax.named_scope("halo_extend"):
+        rl, rr = exchange_halos(local, r, num_devices, axis,
+                                row_axis=row_axis)
+        return jnp.concatenate([rl, local, rr], axis=row_axis)

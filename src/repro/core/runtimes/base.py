@@ -112,9 +112,9 @@ class Runtime(abc.ABC):
     def __init__(self, devices: Optional[Sequence[jax.Device]] = None, **options):
         self.devices = list(devices) if devices is not None else jax.devices()
         self.options = options
-        #: span recorder for `trace_once` (the ``trace=`` option; defaults
-        #: to the shared NULL_TRACER — the timed `measure`/`execute` paths
-        #: never touch it, so tracing-off cannot perturb measurements)
+        #: span recorder for `trace_once` and the layer spans (the
+        #: ``trace=`` option; defaults to the shared NULL_TRACER, so with
+        #: tracing off no span is recorded on any path)
         self.tracer = coerce_tracer(options.get("trace"))
 
     # -- capabilities ------------------------------------------------------
@@ -249,12 +249,13 @@ class Runtime(abc.ABC):
     def _build_traced(self, graph: TaskGraph) -> Callable[[jax.Array], Any]:
         """An executor that records spans into ``self.tracer`` as it runs.
 
-        Default (fused / bsp_scan / overlap — backends whose whole loop
-        lives in one jit, opaque to host-side tracing): two run-level
-        spans — ``dispatch`` is the host call issuing the program(s),
-        ``compute.interior`` the wait for the device to drain. Backends
-        with real host boundaries (bsp, serialized, pallas_step) override
-        this with per-step / per-launch / per-phase spans.
+        Default (fused / bsp_scan / overlap / pallas_step — backends whose
+        whole loop lives in one jit, opaque to host-side tracing): two
+        run-level spans — ``dispatch`` is the host call issuing the
+        program(s), ``compute.interior`` the wait for the device to drain;
+        any layer spans the built program records nest inside them.
+        Backends with real host boundaries (bsp, serialized) override this
+        with per-step spans.
         """
         fn = self.build(graph)
         tr = self.tracer
@@ -274,10 +275,11 @@ class Runtime(abc.ABC):
     def trace_once(self, graph: TaskGraph,
                    init: Optional[jax.Array] = None) -> np.ndarray:
         """Run the graph once recording spans (a SEPARATE execution from
-        `measure` — the timed path stays untouched). The traced executor
-        is warmed up first and the warmup's spans dropped, so compile time
-        never pollutes the attribution; build-time decision records
-        survive. With the null tracer this is just `execute`."""
+        `measure`, whose timed path records at most its layer spans). The
+        traced executor is warmed up first and the warmup's spans dropped,
+        so compile time never pollutes the attribution; build-time
+        decision records and build spans (non-wall) survive. With the null
+        tracer this is just `execute`."""
         tr = self.tracer
         if not tr.enabled:
             return self.execute(graph, init)
@@ -289,7 +291,7 @@ class Runtime(abc.ABC):
         init = jax.block_until_ready(jax.device_put(init))
         fn = self._build_traced(graph)
         mark = len(tr.spans)
-        jax.block_until_ready(fn(_fresh(init)))  # compile + probe warmup
+        jax.block_until_ready(fn(_fresh(init)))  # compile warmup
         del tr.spans[mark:]
         out = fn(_fresh(init))
         return np.asarray(jax.block_until_ready(out))

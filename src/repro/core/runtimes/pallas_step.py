@@ -122,6 +122,7 @@ from repro.kernels.taskbench_step import (
     prepare_step_operands,
 )
 from repro.launch.mesh import make_row_member_mesh
+from repro.obs import layer_span
 
 #: Execution-plan kinds the pattern→plan dispatch resolves to.
 PLAN_HALO = "halo"
@@ -334,9 +335,7 @@ def _extend_state(s: jax.Array, depth: int, num_devices: int,
     multi-hop past the block). Identity at depth 0."""
     if depth == 0:
         return s
-    rl, rr = _halo.exchange_halos(s, depth, num_devices, AXIS,
-                                  row_axis=row_axis)
-    return jnp.concatenate([rl, s, rr], axis=row_axis)
+    return _halo.ring_extend(s, depth, num_devices, AXIS, row_axis=row_axis)
 
 
 def _rebase_rows(rel: jax.Array, *, row_axis: int = 0) -> jax.Array:
@@ -912,24 +911,53 @@ class PallasStepRuntime(_BspBase):
     # ------------------------------------------------------- single graph
 
     def build(self, graph: TaskGraph) -> Callable[[jax.Array], jax.Array]:
+        """Plan, build and place one graph's program — every plan goes
+        through here. The layer spans (repro.obs.layer_span):
+        ``pallas_step.build`` (attrs plan, S, width, grain) holds
+        ``pallas_step.plan`` and the builder's ``pallas_step.operands``
+        (host tables) and ``pallas_step.program`` (jit + shard_map); the
+        returned callable's first call is ``pallas_step.first_call``
+        (trace, lower, compile or cache read, enqueue), every later one
+        ``pallas_step.call``."""
         self._require_support(graph)
-        plan = self._schedule_for_graph(graph)
-        if plan.kind == PLAN_STRIDE:
-            return self._build_plan_stepper(graph, plan.kind)
-        if plan.kind == PLAN_ALLGATHER:
-            if plan.steps_per_launch > 1:
-                return self._build_allgather_blocked(
-                    graph, plan.steps_per_launch)
-            return self._build_plan_stepper(graph, plan.kind)
+        tr = self.tracer
+        with layer_span(tr, "pallas_step.build", width=graph.width,
+                        grain=graph.kernel.iterations) as span:
+            with layer_span(tr, "pallas_step.plan"):
+                plan = self._schedule_for_graph(graph)
+            S = plan.steps_per_launch
+            span.set(plan=plan.kind, S=S)
+            if tr.enabled:
+                _schedule.record_resolution(
+                    tr, plan=plan.kind, steps_per_launch=S,
+                    pipeline=plan.kind == PLAN_HALO and self._pipeline_active(
+                        self._block(graph), S, _patterns.halo_radius(graph),
+                        graph.payload),
+                    model=self._cost_model(graph.payload),
+                    reason=plan.reason, runtime=self.name,
+                    pattern=graph.pattern, width=graph.width,
+                    launches=self._launches(graph.steps, S))
+            if plan.kind == PLAN_STRIDE or (
+                    plan.kind == PLAN_ALLGATHER and S == 1):
+                fn = self._build_plan_stepper(graph, plan.kind)
+            elif plan.kind == PLAN_ALLGATHER:
+                fn = self._build_allgather_blocked(graph, S)
+            elif S > 1:
+                fn = self._build_blocked(graph, S)
+            else:
+                fn = self._build_halo(graph)
+        return _with_call_spans(fn, tr)
+
+    def _build_halo(self, graph: TaskGraph) -> Callable:
+        """S=1 halo plan: per step one ring extend + one megakernel
+        launch, the whole loop in one scanned program."""
         H = _patterns.halo_radius(graph)
-        S = plan.steps_per_launch
-        if S > 1:
-            return self._build_blocked(graph, S)
         unroll = int(self.options.get("unroll", 1))
         mesh = self._mesh()
         D = len(self.devices)
         kw = self._kernel_kw(graph.kernel)
-        idx, wgt, idx0, wgt0 = self._operands(graph, H)
+        with layer_span(self.tracer, "pallas_step.operands"):
+            idx, wgt, idx0, wgt0 = self._operands(graph, H)
 
         def megastep(ext_src, i, w):  # (B|B+2H, P), (B, D'), (B, D')
             return _kops.taskbench_step(ext_src[None], i[None], w[None], **kw)[0]
@@ -947,12 +975,13 @@ class PallasStepRuntime(_BspBase):
             )
             return state
 
-        fn = jax.jit(
-            shard_map(
-                local_run, mesh=mesh, check_vma=False,
-                in_specs=(P(AXIS),) * 5, out_specs=P(AXIS),
+        with layer_span(self.tracer, "pallas_step.program"):
+            fn = jax.jit(
+                shard_map(
+                    local_run, mesh=mesh, check_vma=False,
+                    in_specs=(P(AXIS),) * 5, out_specs=P(AXIS),
+                )
             )
-        )
         sh = NamedSharding(mesh, P(AXIS))
         consts = tuple(
             jax.device_put(jnp.asarray(a), sh) for a in (idx, wgt, idx0, wgt0)
@@ -975,8 +1004,9 @@ class PallasStepRuntime(_BspBase):
         kw0 = self._kernel_kw(graph.kernel)
         kwb = dict(kw0, steps_per_launch=S)
         kwb.pop("block_rows", None)  # blocked path: one program per member
-        idx, wgt, idx0, wgt0 = self._blocked_operands(graph, H)
-        acts = _act_schedule((graph.steps,), graph.steps, S)[:, 0]  # (L, S)
+        with layer_span(self.tracer, "pallas_step.operands"):
+            idx, wgt, idx0, wgt0 = self._blocked_operands(graph, H)
+            acts = _act_schedule((graph.steps,), graph.steps, S)[:, 0]  # (L, S)
         T = graph.steps
         pipelined = self._pipeline_active(self._block(graph), S, H,
                                           graph.payload)
@@ -1016,12 +1046,13 @@ class PallasStepRuntime(_BspBase):
             state, _ = jax.lax.scan(body, state, act_seq, unroll=unroll)
             return state
 
-        fn = jax.jit(
-            shard_map(
-                local_run, mesh=mesh, check_vma=False,
-                in_specs=(P(AXIS),) * 5 + (P(),), out_specs=P(AXIS),
+        with layer_span(self.tracer, "pallas_step.program"):
+            fn = jax.jit(
+                shard_map(
+                    local_run, mesh=mesh, check_vma=False,
+                    in_specs=(P(AXIS),) * 5 + (P(),), out_specs=P(AXIS),
+                )
             )
-        )
         sh = NamedSharding(mesh, P(AXIS))
         rep = NamedSharding(mesh, P())
         consts = tuple(
@@ -1228,7 +1259,8 @@ class PallasStepRuntime(_BspBase):
         unroll = int(self.options.get("unroll", 1))
         mesh = self._mesh()
         T = graph.steps
-        t0, step = self._plan_step_fns(graph, plan)
+        with layer_span(self.tracer, "pallas_step.operands"):
+            t0, step = self._plan_step_fns(graph, plan)
 
         def local_run(local):
             state = t0(local, ())
@@ -1242,9 +1274,10 @@ class PallasStepRuntime(_BspBase):
                 body, state, jnp.arange(1, T), unroll=unroll)
             return state
 
-        fn = jax.jit(
-            shard_map(local_run, mesh=mesh, check_vma=False,
-                      in_specs=P(AXIS), out_specs=P(AXIS)))
+        with layer_span(self.tracer, "pallas_step.program"):
+            fn = jax.jit(
+                shard_map(local_run, mesh=mesh, check_vma=False,
+                          in_specs=P(AXIS), out_specs=P(AXIS)))
         sh = NamedSharding(mesh, P(AXIS))
         return lambda init: fn(jax.device_put(init, sh))
 
@@ -1267,10 +1300,11 @@ class PallasStepRuntime(_BspBase):
         kwb = dict(kw0, steps_per_launch=S)
         kwb.pop("block_rows", None)
         impl = self._gather_impl(graph.width)
-        tables_for, time_varying = self._global_table_fn(graph)
-        acts = _act_schedule((T,), T, S)[:, 0]  # (L, S)
-        # first timestep of each launch (selects the depth tables in-scan)
-        t0s = 1 + np.arange(acts.shape[0], dtype=np.int32) * S
+        with layer_span(self.tracer, "pallas_step.operands"):
+            tables_for, time_varying = self._global_table_fn(graph)
+            acts = _act_schedule((T,), T, S)[:, 0]  # (L, S)
+            # first timestep of each launch (selects the depth tables in-scan)
+            t0s = 1 + np.arange(acts.shape[0], dtype=np.int32) * S
         i0, w0 = _self_tables(B)
 
         def local_run(local, act_seq, t0_seq):
@@ -1296,9 +1330,10 @@ class PallasStepRuntime(_BspBase):
                 body, state, (act_seq, t0_seq), unroll=unroll)
             return state
 
-        fn = jax.jit(
-            shard_map(local_run, mesh=mesh, check_vma=False,
-                      in_specs=(P(AXIS), P(), P()), out_specs=P(AXIS)))
+        with layer_span(self.tracer, "pallas_step.program"):
+            fn = jax.jit(
+                shard_map(local_run, mesh=mesh, check_vma=False,
+                          in_specs=(P(AXIS), P(), P()), out_specs=P(AXIS)))
         sh = NamedSharding(mesh, P(AXIS))
         rep = NamedSharding(mesh, P())
         acts_dev = jax.device_put(jnp.asarray(acts), rep)
@@ -1926,572 +1961,23 @@ class PallasStepRuntime(_BspBase):
             total += 1 + (2 if piped else 1) * (launches - 1)
         return total
 
-    # ------------------------------------------------------------- tracing
-    #
-    # The traced executors re-express each schedule as HOST-stepped jits so
-    # span boundaries exist (the production builders put the whole loop in
-    # one jit, opaque to host timing). Two fidelity rules govern every
-    # builder below:
-    #
-    #   1. numerics are bit-identical to the production path — same
-    #      operands, same kernels, same exchange transports, only the loop
-    #      moved from lax.scan to Python;
-    #   2. the pipelined launch stays ONE program. Splitting boundary /
-    #      exchange / interior into separate jits would serialize them on
-    #      the per-device dispatch queue and destroy the very overlap being
-    #      measured, so the combined launch is timed as a "launch" span and
-    #      its phases are priced by SEPARATE in-jit scan-of-R probes
-    #      (decompose.py then splits each launch wall by probe costs and
-    #      derives the overlap verdict from what the combined wall does
-    #      NOT show).
 
-    def _record_schedule(self, graph: TaskGraph, plan: _ResolvedPlan,
-                         pipelined: bool) -> None:
-        _schedule.record_resolution(
-            self.tracer, plan=plan.kind,
-            steps_per_launch=plan.steps_per_launch, pipeline=pipelined,
-            model=self._cost_model(graph.payload), reason=plan.reason,
-            runtime=self.name, pattern=graph.pattern, width=graph.width,
-            launches=self._launches(graph.steps, plan.steps_per_launch))
+def _with_call_spans(fn: Callable, tracer) -> Callable:
+    """``fn`` with its first call in a ``pallas_step.first_call`` layer
+    span and every later call in ``pallas_step.call``; adds no sync."""
+    first = True
 
-    def _build_traced(self, graph: TaskGraph) -> Callable:
-        self._require_support(graph)
-        plan = self._schedule_for_graph(graph)
-        S = plan.steps_per_launch
-        pipelined = (
-            plan.kind == PLAN_HALO and S > 1
-            and self._pipeline_active(
-                self._block(graph), S, _patterns.halo_radius(graph),
-                graph.payload))
-        self._record_schedule(graph, plan, pipelined)
-        if plan.kind == PLAN_STRIDE:
-            return self._trace_stride_steps(graph)
-        if plan.kind == PLAN_ALLGATHER:
-            if S > 1:
-                return self._trace_allgather_blocked(graph, S)
-            return self._trace_allgather_steps(graph)
-        if S > 1 and pipelined:
-            return self._trace_blocked_pipelined(graph, S)
-        if S > 1:
-            return self._trace_blocked_serial(graph, S)
-        return self._trace_halo_steps(graph)
-
-    def _trace_halo_steps(self, graph: TaskGraph) -> Callable:
-        """Traced S=1 halo plan: per step, one transport span (the ring
-        extend) and one megakernel span."""
-        mesh = self._mesh()
-        D = len(self.devices)
-        H = _patterns.halo_radius(graph)
-        kw = self._kernel_kw(graph.kernel)
-        idx, wgt, idx0, wgt0 = self._operands(graph, H)
-        tr = self.tracer
-        sh = NamedSharding(mesh, P(AXIS))
-
-        k_fn = jax.jit(shard_map(
-            lambda ext, i, w: _kops.taskbench_step(
-                ext[None], i[None], w[None], **kw)[0],
-            mesh=mesh, check_vma=False,
-            in_specs=(P(AXIS),) * 3, out_specs=P(AXIS)))
-        ex_fn = jax.jit(shard_map(
-            lambda s: _extend_state(s, H, D),
-            mesh=mesh, check_vma=False,
-            in_specs=P(AXIS), out_specs=P(AXIS))) if H > 0 else None
-        consts = tuple(jax.device_put(jnp.asarray(a), sh)
-                       for a in (idx, wgt, idx0, wgt0))
-
-        def run(init):
-            i, w, i0, w0 = consts
-            with tr.span("t0_launch", "dispatch", step=0):
-                st = k_fn(jax.device_put(init, sh), i0, w0)
-            with tr.span("t0_kernel", "compute.interior", step=0):
-                st = jax.block_until_ready(st)
-            for t in range(1, graph.steps):
-                if ex_fn is not None:
-                    with _halo.transport_span(
-                            tr, "halo_exchange", impl="ppermute", depth=H,
-                            step=t):
-                        ext = jax.block_until_ready(ex_fn(st))
-                else:
-                    ext = st
-                with tr.span("megakernel", "compute.interior", step=t,
-                             pattern=graph.pattern):
-                    st = jax.block_until_ready(k_fn(ext, i, w))
-            return st
-
-        return run
-
-    def _trace_blocked_serial(self, graph: TaskGraph, S: int) -> Callable:
-        """Traced blocked serial-exchange schedule: per launch, one deep
-        transport span then one S-depth kernel span — the exact pair whose
-        serialization the pipelined schedule exists to break."""
-        mesh = self._mesh()
-        D = len(self.devices)
-        H = _patterns.halo_radius(graph)
-        depth = S * H
-        mode = self._combine_mode()
-        kw0 = self._kernel_kw(graph.kernel)
-        kwb = dict(kw0, steps_per_launch=S)
-        kwb.pop("block_rows", None)
-        idx, wgt, idx0, wgt0 = self._blocked_operands(graph, H)
-        acts = _act_schedule((graph.steps,), graph.steps, S)[:, 0]  # (L, S)
-        tr = self.tracer
-        sh = NamedSharding(mesh, P(AXIS))
-        rep = NamedSharding(mesh, P())
-
-        t0_fn = jax.jit(shard_map(
-            lambda local, i0, w0: _kops.taskbench_step(
-                local[None], i0[None], w0[None], **kw0)[0],
-            mesh=mesh, check_vma=False,
-            in_specs=(P(AXIS),) * 3, out_specs=P(AXIS)))
-        tab_fn = jax.jit(shard_map(
-            lambda i, w: _extend_tables(i, w, depth, D, mode),
-            mesh=mesh, check_vma=False,
-            in_specs=(P(AXIS),) * 2, out_specs=(P(AXIS),) * 2))
-        ex_fn = jax.jit(shard_map(
-            lambda s: _extend_state(s, depth, D),
-            mesh=mesh, check_vma=False,
-            in_specs=P(AXIS), out_specs=P(AXIS)))
-
-        def kern(ext, iext, wext, a):
-            B = ext.shape[0] - 2 * depth
-            nf = _kops.taskbench_step(
-                ext[None], iext[None], wext[None], a[None], **kwb)[0]
-            return jax.lax.slice_in_dim(nf, depth, depth + B, axis=0)
-
-        k_fn = jax.jit(shard_map(
-            kern, mesh=mesh, check_vma=False,
-            in_specs=(P(AXIS),) * 3 + (P(),), out_specs=P(AXIS)))
-        consts = tuple(jax.device_put(jnp.asarray(a), sh)
-                       for a in (idx, wgt, idx0, wgt0))
-        act_rows = [jax.device_put(jnp.asarray(a), rep) for a in acts]
-
-        def run(init):
-            i, w, i0, w0 = consts
-            with tr.span("t0_launch", "dispatch", step=0):
-                st = t0_fn(jax.device_put(init, sh), i0, w0)
-            with tr.span("t0_kernel", "compute.interior", step=0):
-                st = jax.block_until_ready(st)
-            if graph.steps == 1:
-                return st
-            with _halo.transport_span(tr, "table_exchange", impl="ppermute",
-                                      depth=depth, setup=True):
-                iext, wext = jax.block_until_ready(tab_fn(i, w))
-            for l, a in enumerate(act_rows):
-                with _halo.transport_span(tr, "deep_exchange",
-                                          impl="ppermute", depth=depth,
-                                          launch=l):
-                    ext = jax.block_until_ready(ex_fn(st))
-                with tr.span("blocked_kernel", "compute.interior", launch=l,
-                             steps_per_launch=S):
-                    st = jax.block_until_ready(k_fn(ext, iext, wext, a))
-            return st
-
-        return run
-
-    def _trace_blocked_pipelined(self, graph: TaskGraph, S: int) -> Callable:
-        """Traced pipelined schedule: each launch is ONE combined program
-        (boundary -> exchange-start -> interior, exactly the production
-        `_pipelined_launch` body) recorded as a "launch" span, plus three
-        in-jit scan-of-R phase probes whose per-launch costs let
-        decompose.py split each combined wall and prove (or refute) the
-        overlap. Probe outputs are loop-carried — each rep's results feed
-        the next rep's inputs — so neither DCE nor loop-invariant hoisting
-        can elide the work being priced; they run AFTER the launch loop so
-        their wall can never smear into the attributed extent."""
-        mesh = self._mesh()
-        D = len(self.devices)
-        H = _patterns.halo_radius(graph)
-        depth = S * H
-        mode = self._combine_mode()
-        kw0 = self._kernel_kw(graph.kernel)
-        kwb = dict(kw0, steps_per_launch=S)
-        kwb.pop("block_rows", None)
-        impl = self._halo_impl()
-        idx, wgt, idx0, wgt0 = self._blocked_operands(graph, H)
-        acts = _act_schedule((graph.steps,), graph.steps, S)[:, 0]  # (L, S)
-        tr = self.tracer
-        sh = NamedSharding(mesh, P(AXIS))
-        rep = NamedSharding(mesh, P())
-        R = int(self.options.get("trace_probe_reps", 16))
-
-        t0_fn = jax.jit(shard_map(
-            lambda local, i0, w0: _kops.taskbench_step(
-                local[None], i0[None], w0[None], **kw0),
-            mesh=mesh, check_vma=False,
-            in_specs=(P(AXIS),) * 3, out_specs=P(None, AXIS)))
-
-        def setup_local(local, i, w):
-            ph = _phase_tables(i[None], w[None], depth, D, mode)
-            h = _prologue_exchange(local, depth, D, impl)
-            return (*ph, h.recv_left, h.recv_right)
-
-        setup_fn = jax.jit(shard_map(
-            setup_local, mesh=mesh, check_vma=False,
-            in_specs=(P(None, AXIS), P(AXIS), P(AXIS)),
-            out_specs=(P(None, AXIS),) * 6))
-
-        def launch_local(s, hl, hr, a, ii, wi, ib, wb):
-            ph = _PhaseTables(ii, wi, ib, wb)
-            s2, h2 = _pipelined_launch(s, hl, hr, a, ph, depth, D, kwb, impl)
-            return s2, h2.recv_left, h2.recv_right
-
-        launch_fn = jax.jit(shard_map(
-            launch_local, mesh=mesh, check_vma=False,
-            in_specs=(P(None, AXIS),) * 3 + (P(),) + (P(None, AXIS),) * 4,
-            out_specs=(P(None, AXIS),) * 3))
-
-        def ex_probe_local(f, l):
-            def body(c, _):
-                h = _halo.exchange_edges_start(
-                    c[0], c[1], D, AXIS, row_axis=1, impl=impl)
-                return (h.recv_left, h.recv_right), None
-            out, _ = jax.lax.scan(body, (f, l), None, length=R)
+    def run(init):
+        nonlocal first
+        if first:
+            with layer_span(tracer, "pallas_step.first_call"):
+                out = fn(init)
+            first = False
             return out
+        with layer_span(tracer, "pallas_step.call", category="dispatch"):
+            return fn(init)
 
-        ex_probe = jax.jit(shard_map(
-            ex_probe_local, mesh=mesh, check_vma=False,
-            in_specs=(P(None, AXIS),) * 2, out_specs=(P(None, AXIS),) * 2))
-
-        def bd_probe_local(s, hl, hr, a, ib, wb):
-            B = s.shape[1]
-            bl = jnp.concatenate(
-                [hl, jax.lax.slice_in_dim(s, 0, 2 * depth, axis=1)], axis=1)
-            br = jnp.concatenate(
-                [jax.lax.slice_in_dim(s, B - 2 * depth, B, axis=1), hr],
-                axis=1)
-
-            def body(c, _):
-                blo, bro = _kops.taskbench_boundary(
-                    c[0], c[1], ib, wb, a, depth=depth, **kwb)
-                return (jnp.concatenate([blo, bro, blo], axis=1),
-                        jnp.concatenate([bro, blo, bro], axis=1)), None
-
-            out, _ = jax.lax.scan(body, (bl, br), None, length=R)
-            return out
-
-        bd_probe = jax.jit(shard_map(
-            bd_probe_local, mesh=mesh, check_vma=False,
-            in_specs=(P(None, AXIS),) * 3 + (P(),) + (P(None, AXIS),) * 2,
-            out_specs=(P(None, AXIS),) * 2))
-
-        def in_probe_local(s, a, ii, wi):
-            def body(c, _):
-                mid = _kops.taskbench_interior(
-                    c, ii, wi, a, depth=depth, **kwb)
-                B = c.shape[1]
-                return jnp.concatenate([
-                    jax.lax.slice_in_dim(c, 0, depth, axis=1), mid,
-                    jax.lax.slice_in_dim(c, B - depth, B, axis=1)],
-                    axis=1), None
-            out, _ = jax.lax.scan(body, s, None, length=R)
-            return out
-
-        in_probe = jax.jit(shard_map(
-            in_probe_local, mesh=mesh, check_vma=False,
-            in_specs=(P(None, AXIS), P()) + (P(None, AXIS),) * 2,
-            out_specs=P(None, AXIS)))
-
-        consts = tuple(jax.device_put(jnp.asarray(a), sh)
-                       for a in (idx, wgt, idx0, wgt0))
-        act_rows = [jax.device_put(jnp.asarray(a)[None], rep) for a in acts]
-
-        def probe(phase, category, thunk):
-            t0us = tr.now_us()
-            best = _probes._time_best_us(thunk, reps=2)
-            tr.add(f"probe.{phase}", category, t0us, tr.now_us(),
-                   probe=True, phase=phase, per_launch_us=best / R, reps=R,
-                   impl=impl, depth=depth)
-
-        def run(init):
-            i, w, i0, w0 = consts
-            with tr.span("t0_launch", "dispatch", step=0):
-                st = t0_fn(jax.device_put(init, sh), i0, w0)
-            with tr.span("t0_kernel", "compute.interior", step=0):
-                st = jax.block_until_ready(st)
-            if graph.steps == 1:
-                return st[0]
-            with _halo.transport_span(tr, "prologue_exchange", impl=impl,
-                                      depth=depth, setup=True):
-                ii, wi, ib, wb, hl, hr = jax.block_until_ready(
-                    setup_fn(st, i, w))
-            for l, a in enumerate(act_rows):
-                with tr.span("pipelined_launch", "launch", launch=l,
-                             steps_per_launch=S, impl=impl, depth=depth,
-                             kernel_launches=2):
-                    st, hl, hr = jax.block_until_ready(
-                        launch_fn(st, hl, hr, a, ii, wi, ib, wb))
-            steady = act_rows[0]
-            probe("exchange", "exchange", lambda: ex_probe(hl, hr))
-            probe("boundary", "compute.boundary",
-                  lambda: bd_probe(st, hl, hr, steady, ib, wb))
-            probe("interior", "compute.interior",
-                  lambda: in_probe(st, steady, ii, wi))
-            return st[0]
-
-        return run
-
-    def _trace_stride_steps(self, graph: TaskGraph) -> Callable:
-        """Traced stride (butterfly) plan: per step, the period slot's
-        stride picks host-side between an in-block XOR shuffle (no
-        collective — kernel span only) and an off-block XOR permute (one
-        stride transport span, then the kernel span)."""
-        mesh = self._mesh()
-        D = len(self.devices)
-        B = self._block(graph)
-        mode = self._plan_combine(PLAN_STRIDE)
-        kw = self._kernel_kw(graph.kernel, combine=mode)
-        impl = self._halo_impl()
-        period = graph.period
-        strides = _patterns.butterfly_slot_strides(graph)
-        tr = self.tracer
-        sh = NamedSharding(mesh, P(AXIS))
-        dummy_i = jnp.zeros((1, 1), jnp.int32)
-        dummy_w = jnp.zeros((B, 1), WEIGHT_DTYPE)
-        i0, w0 = _self_tables(B)
-
-        def smap(f, n_in=1):
-            return jax.jit(shard_map(
-                f, mesh=mesh, check_vma=False,
-                in_specs=(P(AXIS),) * n_in if n_in > 1 else P(AXIS),
-                out_specs=P(AXIS)))
-
-        fns = {}  # stride -> (exchange jit | None, kernel jit)
-        for s in sorted(set(strides)):
-            ex = smap(lambda local, bs=s // B: _halo.exchange_stride(
-                local, (bs,), D, AXIS, impl=impl)[0]) if s >= B else None
-            if mode == "pair":
-                if s < B:
-                    def kern1(local, s=s):
-                        src = jnp.concatenate(
-                            [local, _xor_swap(local, s)], axis=0)
-                        return _kops.taskbench_step(
-                            src[None], dummy_i[None], dummy_w[None], **kw)[0]
-                    fns[s] = (None, smap(kern1))
-                else:
-                    def kern2(local, partner):
-                        src = jnp.concatenate([local, partner], axis=0)
-                        return _kops.taskbench_step(
-                            src[None], dummy_i[None], dummy_w[None], **kw)[0]
-                    fns[s] = (ex, smap(kern2, 2))
-                continue
-            idx_np, wgt_np, off_block = _stride_slot_tables(B, s)
-            sidx, swgt = jnp.asarray(idx_np), jnp.asarray(wgt_np)
-            if not off_block:
-                def kern1(local, sidx=sidx, swgt=swgt):
-                    return _kops.taskbench_step(
-                        local[None], sidx[None], swgt[None], **kw)[0]
-                fns[s] = (None, smap(kern1))
-            else:
-                def kern2(local, partner, sidx=sidx, swgt=swgt):
-                    src = jnp.concatenate([local, partner], axis=0)
-                    return _kops.taskbench_step(
-                        src[None], sidx[None], swgt[None], **kw)[0]
-                fns[s] = (ex, smap(kern2, 2))
-
-        if mode == "pair":
-            def t0l(local):
-                src = jnp.concatenate([local, local], axis=0)
-                return _kops.taskbench_step(
-                    src[None], dummy_i[None], dummy_w[None], **kw)[0]
-        else:
-            def t0l(local):
-                return _kops.taskbench_step(
-                    local[None], i0[None], w0[None], **kw)[0]
-        t0_fn = smap(t0l)
-
-        def run(init):
-            with tr.span("t0_launch", "dispatch", step=0):
-                st = t0_fn(jax.device_put(init, sh))
-            with tr.span("t0_kernel", "compute.interior", step=0):
-                st = jax.block_until_ready(st)
-            for t in range(1, graph.steps):
-                s = strides[(t - 1) % period]
-                ex, kern = fns[s]
-                if ex is not None:
-                    with _halo.transport_span(tr, "stride_exchange",
-                                              impl=impl, depth=s // B,
-                                              step=t, stride=s):
-                        partner = jax.block_until_ready(ex(st))
-                    args = (st, partner)
-                else:
-                    args = (st,)
-                with tr.span("stride_kernel", "compute.interior", step=t,
-                             stride=s):
-                    st = jax.block_until_ready(kern(*args))
-            return st
-
-        return run
-
-    def _global_tables_host(self, graph: TaskGraph) -> Callable:
-        """Host (numpy) twin of `_global_table_fn`: ``at(t) -> (idx, wgt)``
-        for one timestep — same rotation / period-slot arithmetic, computed
-        host-side so the traced all-gather builders can feed per-step
-        tables without burying the table policy in a jit."""
-        W = graph.width
-        if graph.pattern == "spread":
-            bi, bw = _spread_base_operands(graph)
-
-            def at(t):
-                return (bi + (t - 1)) % W, bw
-
-            return at
-        gi, gw = _global_slot_operands(graph)
-        period = gi.shape[0]
-
-        def at(t):
-            return gi[(t - 1) % period], gw[(t - 1) % period]
-
-        return at
-
-    def _trace_allgather_steps(self, graph: TaskGraph) -> Callable:
-        """Traced per-step all-gather plan: per step, one gather span (the
-        full-state collective) and one megakernel span; this launch's
-        (idx, wgt) tables arrive AXIS-sharded so each device reads exactly
-        the rows production's in-scan dynamic_slice would."""
-        mesh = self._mesh()
-        D = len(self.devices)
-        B = self._block(graph)
-        kw = self._kernel_kw(graph.kernel,
-                             combine=self._plan_combine(PLAN_ALLGATHER))
-        impl = self._gather_impl(graph.width)
-        tr = self.tracer
-        sh = NamedSharding(mesh, P(AXIS))
-        tab_at = self._global_tables_host(graph)
-        i0, w0 = _self_tables(B)
-
-        t0_fn = jax.jit(shard_map(
-            lambda local: _kops.taskbench_step(
-                local[None], i0[None], w0[None], **kw)[0],
-            mesh=mesh, check_vma=False, in_specs=P(AXIS), out_specs=P(AXIS)))
-
-        if (graph.pattern == "all_to_all"
-                and bool(self.options.get("psum_mean", True))):
-            # production's psum-mean lowering, host-stepped: one reduction
-            # span replaces the gather span (same numerics as execute())
-            W = graph.width
-
-            def psum_step(local):
-                mean = _halo.global_mean(local, W, D, AXIS)
-                src = jnp.broadcast_to(mean[None, :], (B, mean.shape[0]))
-                return _kops.taskbench_step(
-                    src[None], i0[None], w0[None], **kw)[0]
-
-            p_fn = jax.jit(shard_map(
-                psum_step, mesh=mesh, check_vma=False,
-                in_specs=P(AXIS), out_specs=P(AXIS)))
-
-            def run(init):
-                with tr.span("t0_launch", "dispatch", step=0):
-                    st = t0_fn(jax.device_put(init, sh))
-                with tr.span("t0_kernel", "compute.interior", step=0):
-                    st = jax.block_until_ready(st)
-                for t in range(1, graph.steps):
-                    with _halo.transport_span(
-                            tr, "gather_psum_mean", impl="psum",
-                            step=t, width=W):
-                        st = jax.block_until_ready(p_fn(st))
-                return st
-
-            return run
-
-        g_fn = jax.jit(shard_map(
-            lambda local: _halo.gather_global(local, D, AXIS, impl=impl),
-            mesh=mesh, check_vma=False, in_specs=P(AXIS), out_specs=P()))
-        k_fn = jax.jit(shard_map(
-            lambda full, i_loc, w_loc: _kops.taskbench_step(
-                full[None], i_loc[None], w_loc[None], **kw)[0],
-            mesh=mesh, check_vma=False,
-            in_specs=(P(), P(AXIS), P(AXIS)), out_specs=P(AXIS)))
-        # per-step tables device_put once at build (the host twin of the
-        # consts the production scan closes over)
-        tabs = []
-        for t in range(1, graph.steps):
-            i_t, w_t = tab_at(t)
-            tabs.append((jax.device_put(jnp.asarray(i_t), sh),
-                         jax.device_put(jnp.asarray(w_t), sh)))
-
-        def run(init):
-            with tr.span("t0_launch", "dispatch", step=0):
-                st = t0_fn(jax.device_put(init, sh))
-            with tr.span("t0_kernel", "compute.interior", step=0):
-                st = jax.block_until_ready(st)
-            for t in range(1, graph.steps):
-                with _halo.transport_span(tr, "gather_global", impl=impl,
-                                          step=t, width=graph.width):
-                    full = jax.block_until_ready(g_fn(st))
-                i_t, w_t = tabs[t - 1]
-                with tr.span("global_kernel", "compute.interior", step=t):
-                    st = jax.block_until_ready(k_fn(full, i_t, w_t))
-            return st
-
-        return run
-
-    def _trace_allgather_blocked(self, graph: TaskGraph, S: int) -> Callable:
-        """Traced blocked all-gather plan: per launch, one gather span and
-        one S-depth kernel span driven by host-precomputed per-launch depth
-        tables (the host twin of production's in-scan ``tables_for``)."""
-        mesh = self._mesh()
-        D = len(self.devices)
-        B = self._block(graph)
-        T = graph.steps
-        kw0 = self._kernel_kw(graph.kernel,
-                              combine=self._plan_combine(PLAN_ALLGATHER))
-        kwb = dict(kw0, steps_per_launch=S)
-        kwb.pop("block_rows", None)
-        impl = self._gather_impl(graph.width)
-        tr = self.tracer
-        sh = NamedSharding(mesh, P(AXIS))
-        rep = NamedSharding(mesh, P())
-        tab_at = self._global_tables_host(graph)
-        time_varying = graph.pattern == "spread" or graph.period > 1
-        acts = _act_schedule((T,), T, S)[:, 0]  # (L, S)
-        i0, w0 = _self_tables(B)
-
-        t0_fn = jax.jit(shard_map(
-            lambda local: _kops.taskbench_step(
-                local[None], i0[None], w0[None], **kw0)[0],
-            mesh=mesh, check_vma=False, in_specs=P(AXIS), out_specs=P(AXIS)))
-        g_fn = jax.jit(shard_map(
-            lambda local: _halo.gather_global(local, D, AXIS, impl=impl),
-            mesh=mesh, check_vma=False, in_specs=P(AXIS), out_specs=P()))
-
-        def kern(full, i_t, w_t, a):
-            nf = _kops.taskbench_step(
-                full[None], i_t[None], w_t[None], a[None], **kwb)[0]
-            r0 = jax.lax.axis_index(AXIS) * B
-            return jax.lax.dynamic_slice_in_dim(nf, r0, B, axis=0)
-
-        k_fn = jax.jit(shard_map(
-            kern, mesh=mesh, check_vma=False,
-            in_specs=(P(),) * 4, out_specs=P(AXIS)))
-        launches = []
-        for l, a in enumerate(acts):
-            tt0 = 1 + l * S
-            if time_varying:
-                pairs = [tab_at(t) for t in range(tt0, tt0 + S)]
-                i_t = np.stack([p[0] for p in pairs])
-                w_t = np.stack([p[1] for p in pairs])
-            else:
-                i_t, w_t = tab_at(1)
-            launches.append((jax.device_put(jnp.asarray(i_t), rep),
-                             jax.device_put(jnp.asarray(w_t), rep),
-                             jax.device_put(jnp.asarray(a), rep)))
-
-        def run(init):
-            with tr.span("t0_launch", "dispatch", step=0):
-                st = t0_fn(jax.device_put(init, sh))
-            with tr.span("t0_kernel", "compute.interior", step=0):
-                st = jax.block_until_ready(st)
-            for l, (i_t, w_t, a) in enumerate(launches):
-                with _halo.transport_span(tr, "gather_global", impl=impl,
-                                          launch=l, width=graph.width):
-                    full = jax.block_until_ready(g_fn(st))
-                with tr.span("blocked_global_kernel", "compute.interior",
-                             launch=l, steps_per_launch=S):
-                    st = jax.block_until_ready(k_fn(full, i_t, w_t, a))
-            return st
-
-        return run
+    return run
 
 
 def _stack_operands(ops4):

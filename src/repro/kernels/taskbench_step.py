@@ -328,12 +328,13 @@ def _blocked_call(src, idx, wgt, act, *, kind, iterations, scratch,
     lane, sublane = (1, 1) if interpret else (LANE, SUBLANE)
     pad_p = (-payload) % lane
     pad_m = (-M) % sublane
-    srcp = jnp.pad(src, ((0, 0), (0, pad_m), (0, pad_p)))
     row_axis = 2 if time_varying else 1
     tab_pad = [(0, 0)] * wgt.ndim
     tab_pad[row_axis] = (0, pad_m)
-    idxp = idx if combine == "window" else jnp.pad(idx, tab_pad)
-    wgtp = jnp.pad(wgt, tab_pad)
+    with jax.named_scope("lane_pad"):
+        srcp = jnp.pad(src, ((0, 0), (0, pad_m), (0, pad_p)))
+        idxp = idx if combine == "window" else jnp.pad(idx, tab_pad)
+        wgtp = jnp.pad(wgt, tab_pad)
     Mp, Pp = srcp.shape[1], srcp.shape[2]
     if combine == "window":
         idx_block = pl.BlockSpec((1, 1, 1), lambda k: (k, 0, 0))
@@ -371,8 +372,10 @@ def _blocked_call(src, idx, wgt, act, *, kind, iterations, scratch,
         out_specs=pl.BlockSpec((1, Mp, Pp), lambda k: (k, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((K, Mp, Pp), src.dtype),
         interpret=interpret,
+        name="taskbench_step_blocked",
     )(srcp, idxp, wgtp, act)
-    return out[:, :M, :payload]
+    with jax.named_scope("lane_slice"):
+        return out[:, :M, :payload]
 
 
 @functools.partial(
@@ -479,10 +482,11 @@ def taskbench_step_pallas(
         pad_s = max(pad_w, (-S) % sublane)
     else:
         pad_s = (-S) % sublane
-    srcp = jnp.pad(src, ((0, 0), (0, pad_s), (0, pad_p)))
-    idxp = (idx if combine in ("window", "pair")
-            else jnp.pad(idx, ((0, 0), (0, pad_w), (0, 0))))
-    wgtp = jnp.pad(wgt, ((0, 0), (0, pad_w), (0, 0)))
+    with jax.named_scope("lane_pad"):
+        srcp = jnp.pad(src, ((0, 0), (0, pad_s), (0, pad_p)))
+        idxp = (idx if combine in ("window", "pair")
+                else jnp.pad(idx, ((0, 0), (0, pad_w), (0, 0))))
+        wgtp = jnp.pad(wgt, ((0, 0), (0, pad_w), (0, 0)))
     Sp, Pp = srcp.shape[1], srcp.shape[2]
     Wp = W + pad_w
     idx_block = (
@@ -511,8 +515,10 @@ def taskbench_step_pallas(
         out_specs=pl.BlockSpec((1, block_rows, Pp), lambda k, i: (k, i, 0)),
         out_shape=jax.ShapeDtypeStruct((K, Wp, Pp), src.dtype),
         interpret=interpret,
+        name="taskbench_step_s1",
     )(srcp, idxp, wgtp)
-    return out[:, :W, :payload]
+    with jax.named_scope("lane_slice"):
+        return out[:, :W, :payload]
 
 
 def taskbench_step_interior(

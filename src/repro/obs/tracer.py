@@ -20,23 +20,31 @@ a category tag from the fixed taxonomy:
 
 Two non-wall categories exist for structured records:
 
-  launch             a COMPOSITE interval — one pipelined launch whose
-                     boundary/exchange/interior phases ran inside a single
-                     XLA program (splitting them into separate dispatches
-                     would serialize the very overlap being measured).
-                     decompose.py apportions these using probe spans.
+  build              set-up phases of a runtime's build (planning, host
+                     operand tables, program construction, the first call
+                     that compiles); recorded, never attributed as run wall
   decision           zero-length records (scheduler verdicts etc.); their
                      attrs are the payload, they carry no wall.
 
 Tracing is OFF by default: runtimes hold the shared :data:`NULL_TRACER`,
 whose ``span()`` returns one reusable no-op context (no allocation, no
 timestamp) — the <1%-overhead contract tests/test_obs.py asserts.
+
+Layer spans (:func:`layer_span`) mark the boundaries of the path the chip
+runs. Each one is a ``jax.profiler.TraceAnnotation`` (so it lands on the
+profiler's host plane, on the device trace's clock, whenever a profiler
+session is active), a ``(calls, seconds)`` entry in the process-wide
+counter table read by :func:`counters`, and a :class:`Span` when the
+runtime's tracer is enabled.
 """
 from __future__ import annotations
 
 import dataclasses
+import threading
 import time
 from typing import Any, Dict, List, Tuple, Union
+
+from jax.profiler import TraceAnnotation
 
 #: The attribution taxonomy (every microsecond of wall lands in one).
 CATEGORIES = (
@@ -52,12 +60,12 @@ CATEGORIES = (
 #: Wall category for fault detection/recovery work (repro.resilience).
 CAT_FAULT = "fault"
 
-#: Composite interval: one pipelined launch, phases fused in-program.
-CAT_LAUNCH = "launch"
+#: Set-up phase of a build: recorded, never attributed as run wall.
+CAT_BUILD = "build"
 #: Zero-length structured record (scheduler decisions etc.).
 CAT_DECISION = "decision"
 
-_KNOWN = set(CATEGORIES) | {CAT_LAUNCH, CAT_DECISION}
+_KNOWN = set(CATEGORIES) | {CAT_BUILD, CAT_DECISION}
 
 
 @dataclasses.dataclass
@@ -206,3 +214,76 @@ def coerce_tracer(opt) -> TracerLike:
         return Tracer()
     raise ValueError(f"cannot interpret trace option {opt!r}: use "
                      f"True/False, 'on', or a Tracer instance")
+
+
+# ----------------------------------------------------------- layer spans
+
+#: name -> [calls, seconds], over every layer span of the process
+_COUNTERS: Dict[str, List[float]] = {}
+_COUNTERS_LOCK = threading.Lock()
+
+
+class _LayerSpan:
+    """One layer span: a profiler annotation, a counter entry and, when
+    the tracer is enabled, a recorded Span (see :func:`layer_span`)."""
+
+    __slots__ = ("_name", "_ann", "_span", "_t0")
+
+    def __init__(self, tracer: TracerLike, name: str, category: str,
+                 attrs: Dict[str, Any]):
+        self._name = name
+        self._ann = TraceAnnotation(name, **attrs)
+        self._span = (tracer.span(name, category, **attrs)
+                      if tracer.enabled else None)
+
+    def set(self, **attrs) -> None:
+        """Attach attrs known only once the span is open (e.g. the plan a
+        build resolved) to the annotation and the recorded span."""
+        self._ann.set_metadata(**attrs)
+        if self._span is not None:
+            self._span._attrs.update(attrs)
+
+    def __enter__(self) -> "_LayerSpan":
+        self._ann.__enter__()
+        if self._span is not None:
+            self._span.__enter__()
+        self._t0 = time.perf_counter()
+        return self
+
+    def __exit__(self, *exc) -> bool:
+        dt = time.perf_counter() - self._t0
+        if self._span is not None:
+            self._span.__exit__(*exc)
+        self._ann.__exit__(*exc)
+        with _COUNTERS_LOCK:
+            c = _COUNTERS.get(self._name)
+            if c is None:
+                _COUNTERS[self._name] = [1, dt]
+            else:
+                c[0] += 1
+                c[1] += dt
+        return False
+
+
+def layer_span(tracer: TracerLike, name: str, *,
+               category: str = CAT_BUILD, **attrs) -> _LayerSpan:
+    """Context manager marking one layer boundary of the real path.
+
+    Opens ``jax.profiler.TraceAnnotation(name)`` with ``attrs`` as its
+    stats (a no-op without a profiler session), adds the span's wall to
+    the process-wide ``(calls, seconds)`` counter under ``name`` (always),
+    and records a ``category`` Span into ``tracer`` when it is enabled.
+    """
+    return _LayerSpan(tracer, name, category, attrs)
+
+
+def counters() -> Dict[str, Tuple[int, float]]:
+    """Snapshot of the layer-span counters: name -> (calls, seconds)."""
+    with _COUNTERS_LOCK:
+        return {k: (int(c), s) for k, (c, s) in _COUNTERS.items()}
+
+
+def reset_counters() -> None:
+    """Clear the layer-span counter table."""
+    with _COUNTERS_LOCK:
+        _COUNTERS.clear()

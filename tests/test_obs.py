@@ -1,14 +1,17 @@
-"""The span tracer, exporters, and wall decomposition (DESIGN.md §10).
+"""The span tracer, layer spans, exporters, and wall decomposition
+(DESIGN.md §10).
 
-Three layers of coverage:
+Four layers of coverage:
 
   * pure-unit: Span/Tracer semantics (nesting depth, category validation,
     the NullTracer fast path), exporter schemas, and the decompose interval
-    math + overlap verdict on SYNTHETIC spans with known answers;
+    math on SYNTHETIC spans with known answers;
   * parity: for every backend, the traced executor built by
     ``_build_traced`` must be numerically identical to the production
     ``execute`` path — tracing is evidence, never a different program
     (single-device in-process; the 2-device matrix runs in a subprocess);
+  * layer spans: pallas_step's build and calls land in the profiler's host
+    plane in order and in the process-wide counter table, on every plan;
   * the off-by-default contract: a disabled tracer's per-span cost times
     the spans-per-step rate must stay under 1% of a measured step wall.
 """
@@ -23,26 +26,23 @@ import numpy as np
 import pytest
 
 from repro.obs import (
+    CAT_BUILD,
     CAT_DECISION,
-    CAT_LAUNCH,
     CATEGORIES,
     NULL_TRACER,
     NullTracer,
     Span,
     Tracer,
     coerce_tracer,
+    counters,
+    reset_counters,
     summarize,
     to_chrome_trace,
     union_us,
     write_chrome_trace,
     write_jsonl,
 )
-from repro.obs.decompose import (
-    category_walls,
-    overlap_verdict,
-    probe_costs,
-    wall_extent_us,
-)
+from repro.obs.decompose import category_walls, wall_extent_us
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
@@ -71,7 +71,7 @@ def test_unknown_category_rejected():
     with pytest.raises(ValueError, match="unknown span category"):
         tr.add("x", "comms", 0.0, 1.0)
     # every taxonomy member and both structured categories are accepted
-    for cat in CATEGORIES + (CAT_LAUNCH,):
+    for cat in CATEGORIES + (CAT_BUILD,):
         with tr.span("x", cat):
             pass
 
@@ -194,72 +194,24 @@ def test_category_walls_no_double_count_and_idle():
         Span("b", "dispatch", 5.0, 12.0),     # overlaps a: union, not sum
         Span("c", "exchange", 20.0, 30.0),
         Span("d", CAT_DECISION, 1.0, 1.0),    # never attributed
+        Span("e", CAT_BUILD, -50.0, 0.0),     # set-up: no wall, no extent
     ]
     walls = category_walls(spans)
     assert walls["dispatch"] == 12.0
     assert walls["exchange"] == 10.0
+    assert CAT_BUILD not in walls
     # extent [0, 30], gap (12, 20) -> idle
     assert wall_extent_us(spans) == 30.0
     assert walls["idle"] == pytest.approx(8.0)
     s = summarize(spans)
-    assert s["schema"] == 1 and s["span_count"] == 4
+    assert s["schema"] == 1 and s["span_count"] == 5
     assert sum(s["fractions"].values()) == pytest.approx(1.0)
     assert s["decisions"] == [{"name": "d"}]
-
-
-def _probe(phase, cost):
-    return Span(f"probe.{phase}", "exchange", 100.0, 101.0, 0,
-                {"probe": True, "phase": phase, "per_launch_us": cost})
-
-
-def test_launch_split_known_answer():
-    # C=100, Bd=20, I=70, E=40: boundary+interior leave 10us visible,
-    # so 30us of the exchange rode under compute.
-    spans = [Span("L", CAT_LAUNCH, 0.0, 100.0),
-             _probe("boundary", 20.0), _probe("interior", 70.0),
-             _probe("exchange", 40.0)]
-    assert probe_costs(spans) == {
-        "boundary": 20.0, "interior": 70.0, "exchange": 40.0}
-    walls = category_walls(spans)
-    assert walls["compute.boundary"] == 20.0
-    assert walls["compute.interior"] == 70.0
-    assert walls["exchange"] == 10.0
-    assert walls["dispatch"] == 0.0
-    v = overlap_verdict(spans)
-    assert v["verdict"] == "hidden"
-    assert v["hidden_fraction"] == pytest.approx(0.75)
-    assert v["exchange_hidden_us"] == pytest.approx(30.0)
-
-
-def test_launch_split_visible_and_slack():
-    # C=140 > Bd+I+E=130: the whole exchange is visible, 10us of host
-    # slack lands in dispatch, verdict flips to "visible".
-    spans = [Span("L", CAT_LAUNCH, 0.0, 140.0),
-             _probe("boundary", 20.0), _probe("interior", 70.0),
-             _probe("exchange", 40.0)]
-    walls = category_walls(spans)
-    assert walls["exchange"] == 40.0
-    assert walls["dispatch"] == pytest.approx(10.0)
-    v = overlap_verdict(spans)
-    assert v["verdict"] == "visible"
-    assert v["hidden_fraction"] == 0.0
-
-
-def test_overlap_verdict_edge_cases():
-    assert overlap_verdict([Span("k", "compute.interior", 0, 5)]) is None
-    v = overlap_verdict([Span("L", CAT_LAUNCH, 0.0, 10.0)])
-    assert v["verdict"] == "unavailable"
-    # probe spans are excluded from extent/attribution
-    spans = [Span("k", "exchange", 0.0, 10.0),
-             _probe("exchange", 5.0)]
-    assert wall_extent_us(spans) == 10.0
-    assert category_walls(spans)["exchange"] == 10.0
 
 
 def test_summarize_empty():
     s = summarize([])
     assert s["wall_us"] == 0.0 and s["span_count"] == 0
-    assert s["overlap"] is None
 
 
 # --------------------------------------------------- schedule decisions --
@@ -338,7 +290,8 @@ PALLAS_CASES = [
                          ids=[c[0] for c in PALLAS_CASES])
 def test_pallas_step_traced_matches_execute(label, pattern, gkw, opts):
     """Every traced pallas_step plan path is bit-compatible with the
-    production executor AND records a plan decision."""
+    production executor AND records a plan decision and its layer spans:
+    the build (non-wall, with the resolved plan) and the traced call."""
     from repro.core import get_runtime
 
     g = _graph(pattern, **gkw)
@@ -352,6 +305,10 @@ def test_pallas_step_traced_matches_execute(label, pattern, gkw, opts):
     assert d["name"] == "schedule.resolve"
     assert d["plan"] in ("halo", "stride", "allgather")
     assert d["runtime"] == "pallas_step"
+    spans = {sp.name: sp for sp in rt.tracer.spans}
+    assert spans["pallas_step.build"].category == CAT_BUILD
+    assert spans["pallas_step.build"].attrs["plan"] == d["plan"]
+    assert spans["pallas_step.call"].category == "dispatch"
 
 
 def test_trace_once_null_tracer_is_plain_execute():
@@ -380,26 +337,85 @@ def test_trace_once_warmup_does_not_duplicate_spans():
     assert len(rt.tracer.spans) == n1
 
 
-def test_pallas_pipelined_trace_has_probes_and_verdict():
-    """The pipelined path records composite launch spans plus the three
-    phase probes, so the decomposition yields an overlap verdict (the
-    physics at tiny CPU shapes says 'visible' — the assertion is that the
-    verdict machinery produces a well-formed answer, not which way)."""
-    from repro.core import get_runtime
+# ------------------------------------------------------------ layer spans --
 
-    g = _graph("stencil_1d", width=32, steps=9)
-    rt = get_runtime("pallas_step", trace=True, steps_per_launch=4)
-    rt.trace_once(g)
-    spans = rt.tracer.spans
-    launches = [s for s in spans if s.category == CAT_LAUNCH]
-    assert launches, "no composite launch spans — pipeline did not engage"
-    costs = probe_costs(spans)
-    assert set(costs) == {"boundary", "exchange", "interior"}
-    assert all(v > 0 for v in costs.values())
-    v = summarize(spans)["overlap"]
-    assert v["verdict"] in ("hidden", "visible")
-    assert 0.0 <= v["hidden_fraction"] <= 1.0
-    assert v["launches"] == len(launches)
+LAYER_SPANS = ("pallas_step.build", "pallas_step.plan",
+               "pallas_step.operands", "pallas_step.program",
+               "pallas_step.first_call", "pallas_step.call")
+
+
+def _host_events(trace_dir):
+    """(name, start_ns, end_ns) of the layer spans on the profiler's host
+    planes of the one trace under ``trace_dir``."""
+    from jax.profiler import ProfileData
+
+    (path,) = list((trace_dir).rglob("*.xplane.pb"))
+    data = ProfileData.from_file(str(path))
+    return sorted(
+        (e.name, e.start_ns, e.start_ns + e.duration_ns)
+        for plane in data.planes if plane.name.startswith("/host:")
+        for line in plane.lines for e in line.events
+        if e.name in LAYER_SPANS)
+
+
+def test_pallas_step_layer_spans_land_on_the_profiler_host_plane(tmp_path):
+    """Under a profiler session, one build and two calls of a halo graph
+    leave the layer spans on the host plane, named exactly, nested and in
+    time order: build holding plan < operands < program, then first_call,
+    then call."""
+    import jax
+
+    from repro.core import get_runtime
+    from repro.core.task_kernels import initial_state
+
+    g = _graph("stencil_1d")
+    init = initial_state(g.width, g.payload, g.seed)
+    rt = get_runtime("pallas_step")
+    with jax.profiler.trace(str(tmp_path)):
+        fn = rt.build(g)
+        jax.block_until_ready(fn(init))
+        jax.block_until_ready(fn(init))
+    ev = {}
+    for name, start, end in _host_events(tmp_path):
+        assert name not in ev, f"{name} recorded twice"
+        ev[name] = (start, end)
+    assert set(ev) == set(LAYER_SPANS)
+    b0, b1 = ev["pallas_step.build"]
+    inner = [ev[n] for n in ("pallas_step.plan", "pallas_step.operands",
+                             "pallas_step.program")]
+    assert all(b0 <= s <= e <= b1 for s, e in inner)
+    assert [s for s, _ in inner] == sorted(s for s, _ in inner)
+    assert inner[0][1] <= inner[1][0] and inner[1][1] <= inner[2][0]
+    assert b1 <= ev["pallas_step.first_call"][0]
+    assert ev["pallas_step.first_call"][1] <= ev["pallas_step.call"][0]
+
+
+@pytest.mark.parametrize("label,pattern,gkw,opts", PALLAS_CASES,
+                         ids=[c[0] for c in PALLAS_CASES])
+def test_pallas_step_layer_counters(label, pattern, gkw, opts):
+    """On every plan path, one build and two calls count build, operands,
+    first_call and call once each, each with time spent, with tracing
+    off, and the built program still computes what execute does."""
+    import jax
+
+    from repro.core import get_runtime
+    from repro.core.task_kernels import initial_state
+
+    g = _graph(pattern, **gkw)
+    ref = get_runtime("pallas_step", **opts).execute(g)
+    init = initial_state(g.width, g.payload, g.seed)
+    rt = get_runtime("pallas_step", **opts)
+    assert rt.tracer is NULL_TRACER
+    reset_counters()
+    fn = rt.build(g)
+    jax.block_until_ready(fn(init))
+    out = np.asarray(jax.block_until_ready(fn(init)))
+    c = counters()
+    names = ("pallas_step.build", "pallas_step.operands",
+             "pallas_step.first_call", "pallas_step.call")
+    assert {n: c[n][0] for n in names} == dict.fromkeys(names, 1)
+    assert all(c[n][1] > 0 for n in names)
+    np.testing.assert_array_equal(out, ref)
 
 
 def test_traced_parity_two_devices_subprocess():

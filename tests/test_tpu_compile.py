@@ -9,6 +9,9 @@ the same tests and only the worker given this file loads the TPU library.
 """
 import functools
 import os
+import re
+import sys
+from pathlib import Path
 
 import jax
 import jax.numpy as jnp
@@ -23,6 +26,12 @@ from repro.kernels.taskbench_step import (
     taskbench_step_interior,
     taskbench_step_pallas,
 )
+
+ROOT = Path(__file__).resolve().parents[1]
+if str(ROOT) not in sys.path:  # `python -m pytest` adds cwd; be explicit
+    sys.path.insert(0, str(ROOT))
+
+from bench.trace_reduce import KERNEL  # noqa: E402
 
 W = 4096  # the chip smoke's width
 PAYLOAD = 64
@@ -143,3 +152,26 @@ def test_kernel_compiles_for_v5e(name, one_chip, no_compile_cache):
     fn, shapes = CASES[name]
     text = _compile(fn, *(_sds(one_chip, s, d) for s, d in shapes))
     assert "tpu_custom_call" in text, f"{name}: no Mosaic kernel in module"
+
+
+#: launch kind -> (its pallas_call's name, the CASES entry that compiles it)
+LAUNCH_NAMES = {
+    "s1": ("taskbench_step_s1", "window_s1"),
+    "blocked": ("taskbench_step_blocked", "window_blocked_k4_s8"),
+}
+
+
+@pytest.mark.parametrize("launch", sorted(LAUNCH_NAMES))
+def test_kernel_instruction_names_match_the_trace_reducer(
+        launch, one_chip, no_compile_cache):
+    """Each launch kind's custom call keeps its explicit name in the
+    compiled module, and the benchmark's trace reduction finds every
+    Task Bench custom call by that name."""
+    name, case = LAUNCH_NAMES[launch]
+    fn, shapes = CASES[case]
+    text = _compile(fn, *(_sds(one_chip, s, d) for s, d in shapes))
+    calls = re.findall(r"%([\w.-]+) = \S+ custom-call\(.*"
+                       r'custom_call_target="tpu_custom_call"', text)
+    assert calls, f"{launch}: no Mosaic kernel in module"
+    assert all(c.startswith(name) for c in calls), calls
+    assert all(KERNEL.search(c) for c in calls), calls
