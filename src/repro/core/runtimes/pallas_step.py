@@ -115,6 +115,7 @@ from repro.core.task_kernels import KernelSpec
 from repro.kernels import ops as _kops
 from repro.kernels import probes as _probes
 from repro.kernels import schedule as _schedule
+from repro.kernels.bodies import LANE, SUBLANE
 from repro.kernels.taskbench_step import (
     WEIGHT_ACCUM_DTYPE,
     WEIGHT_DTYPE,
@@ -336,6 +337,91 @@ def _extend_state(s: jax.Array, depth: int, num_devices: int,
     if depth == 0:
         return s
     return _halo.ring_extend(s, depth, num_devices, AXIS, row_axis=row_axis)
+
+
+class _CarryLayout(NamedTuple):
+    """Rows and lanes of the S=1 window loop's carry, per member: owned
+    rows ``[offset, offset + rows)`` between two ``offset``-row halo
+    blocks, the ``halo`` rows next to the owned ones holding the ring
+    neighbours' edges. ``offset`` and ``rows_p`` are whole sublane tiles
+    and ``lanes`` whole lane tiles, so a step touches the full state only
+    inside the megakernel."""
+
+    halo: int
+    offset: int
+    rows: int
+    rows_p: int
+    lanes: int
+
+
+def _carry_layout(rows: int, halo: int, payload: int,
+                  tile: Optional[Tuple[int, int]] = None) -> _CarryLayout:
+    """The carry for ``rows`` owned rows; ``tile`` (sublanes, lanes)
+    defaults to the chip's (8, 128), and to (1, 1) in interpret mode."""
+    if tile is None:
+        tile = (1, 1) if _kops._interpret() else (SUBLANE, LANE)
+    sub, lane = tile
+    return _CarryLayout(halo=halo, offset=-(-halo // sub) * sub, rows=rows,
+                        rows_p=-(-rows // sub) * sub,
+                        lanes=-(-payload // lane) * lane)
+
+
+def _carry_halos(carry: jax.Array, lay: _CarryLayout,
+                 num_devices: int) -> jax.Array:
+    """Refresh the (K, M, Pp) carry's halo rows in place: the ring
+    exchange of the owned block's ``halo`` edge rows each way, written
+    next to the owned rows. Identity at halo 0."""
+    if lay.halo == 0:
+        return carry
+    with jax.named_scope("halo_update"):
+        owned = jax.lax.slice_in_dim(carry, lay.offset,
+                                     lay.offset + lay.rows, axis=1)
+        rl, rr = _halo.exchange_halos(owned, lay.halo, num_devices, AXIS,
+                                      row_axis=1)
+        carry = jax.lax.dynamic_update_slice_in_dim(
+            carry, rl, lay.offset - lay.halo, axis=1)
+        return jax.lax.dynamic_update_slice_in_dim(
+            carry, rr, lay.offset + lay.rows, axis=1)
+
+
+def _window_carry_run(local: jax.Array, w: jax.Array, w0: jax.Array, *,
+                      lay: _CarryLayout, steps: int, num_devices: int,
+                      kw: dict, unroll: int,
+                      member_steps: Optional[jax.Array] = None) -> jax.Array:
+    """The S=1 window loop over a (K, B, P) block: pad once into the
+    carry layout, the t=0 body-only launch, then per step the halo
+    refresh and one megakernel launch, and slice once at the end.
+    ``member_steps`` (K,) freezes member k once t reaches its own T.
+
+    The scan runs at least two steps an iteration: a launch writes a new
+    buffer, and with two launches XLA alternates the carry between two
+    buffers where with one it copies the whole state back into the loop's
+    buffer every step."""
+    K, B, payload = local.shape
+
+    def launch(c, wt):
+        return _kops.taskbench_carry(c, wt, offset=lay.offset,
+                                     payload=payload, **kw)
+
+    pad_rows = ((0, 0), (0, lay.rows_p - B), (0, 0))
+    with jax.named_scope("lane_pad"):
+        carry = jnp.pad(local, ((0, 0),
+                                (lay.offset, lay.rows_p - B + lay.offset),
+                                (0, lay.lanes - payload)))
+        wp, w0p = jnp.pad(w, pad_rows), jnp.pad(w0, pad_rows)
+    carry = launch(carry, w0p)  # t=0: body only
+    if steps > 1:
+        def body(c, t):
+            nxt = launch(_carry_halos(c, lay, num_devices), wp)
+            if member_steps is not None:
+                nxt = jnp.where((t < member_steps)[:, None, None], nxt, c)
+            return nxt, None
+
+        ts = None if member_steps is None else jnp.arange(1, steps)
+        carry, _ = jax.lax.scan(body, carry, ts, length=steps - 1,
+                                unroll=max(2, unroll))
+    with jax.named_scope("lane_slice"):
+        return carry[:, lay.offset:lay.offset + B, :payload]
 
 
 def _rebase_rows(rel: jax.Array, *, row_axis: int = 0) -> jax.Array:
@@ -948,9 +1034,56 @@ class PallasStepRuntime(_BspBase):
                 fn = self._build_halo(graph)
         return _with_call_spans(fn, tr)
 
-    def _build_halo(self, graph: TaskGraph) -> Callable:
-        """S=1 halo plan: per step one ring extend + one megakernel
-        launch, the whole loop in one scanned program."""
+    def _build_halo(self, graph: TaskGraph, *,
+                    tile: Optional[Tuple[int, int]] = None) -> Callable:
+        """S=1 halo plan, the whole loop in one scanned program.
+
+        Under the window combine the state rides the scan in the
+        megakernel's own layout (``_CarryLayout``: tile-padded rows and
+        lanes, an aligned halo block each side), padded once before the
+        scan and sliced once after it; a step is one megakernel launch
+        on the carry plus the ring exchange of the 2*H edge rows
+        written into its halo blocks (none at H = 0). ``tile`` overrides
+        the (sublanes, lanes) tile, so the chip's (8, 128) layout also
+        runs in interpret mode. The gather/onehot combines and a
+        ``block_rows`` row grid keep the per-step extend of
+        ``_build_halo_extend``."""
+        if self._combine_mode() != "window" or self.options.get("block_rows"):
+            return self._build_halo_extend(graph)
+        H = _patterns.halo_radius(graph)
+        unroll = int(self.options.get("unroll", 1))
+        mesh = self._mesh()
+        D = len(self.devices)
+        spec = graph.kernel
+        kw = dict(kind=spec.kind, iterations=spec.iterations,
+                  scratch=spec.scratch)
+        lay = _carry_layout(self._block(graph), H, graph.payload, tile)
+        with layer_span(self.tracer, "pallas_step.operands"):
+            _, wgt, _, wgt0 = self._operands(graph, H)
+
+        def local_run(local, w, w0):  # (B, P), (B, 2H+1), (B, 1)
+            return _window_carry_run(
+                local[None], w[None], w0[None], lay=lay, steps=graph.steps,
+                num_devices=D, kw=kw, unroll=unroll)[0]
+
+        with layer_span(self.tracer, "pallas_step.program"):
+            fn = jax.jit(
+                shard_map(
+                    local_run, mesh=mesh, check_vma=False,
+                    in_specs=(P(AXIS),) * 3, out_specs=P(AXIS),
+                )
+            )
+        sh = NamedSharding(mesh, P(AXIS))
+        consts = tuple(
+            jax.device_put(jnp.asarray(a), sh) for a in (wgt, wgt0)
+        )
+        return lambda init: fn(jax.device_put(init, sh), *consts)
+
+    def _build_halo_extend(self, graph: TaskGraph) -> Callable:
+        """S=1 halo plan under the gather/onehot combines or a row grid:
+        per step one ring extend + one megakernel launch on the
+        [halo | block | halo] rows, the whole loop in one scanned
+        program."""
         H = _patterns.halo_radius(graph)
         unroll = int(self.options.get("unroll", 1))
         mesh = self._mesh()
@@ -1380,8 +1513,15 @@ class PallasStepRuntime(_BspBase):
             stacked=False,
         )
 
-    def _build_ensemble_stacked(self, ensemble: GraphEnsemble) -> Callable:
+    def _build_ensemble_stacked(
+        self, ensemble: GraphEnsemble, *,
+        tile: Optional[Tuple[int, int]] = None,
+    ) -> Callable:
         """All K members' combines + bodies in ONE megakernel launch/step.
+
+        Under the window combine with no ``block_rows`` the stacked state
+        rides the scan in the carry layout of ``_build_halo`` (``tile``
+        as there); otherwise each step extends it by the ring exchange.
 
         With ``member_shards`` Dk > 1 the shard_map runs over the 2D
         (row, member) mesh: the K axis splits Dk ways (so each device
@@ -1406,10 +1546,19 @@ class PallasStepRuntime(_BspBase):
         ops4 = [self._operands(g, H, block=g.width // Dr) for g in members]
         idx, wgt, idx0, wgt0 = _stack_operands(ops4)
 
+        carried = kw["combine"] == "window" and "block_rows" not in kw
+        lay = _carry_layout(members[0].width // Dr, H, members[0].payload,
+                            tile) if carried else None
+
         def megastep(ext_src, i, w):  # (K, S, P), (K, B, D'), (K, B, D')
             return _kops.taskbench_step(ext_src, i, w, **kw)
 
         def local_run(local, i, w, i0, w0, msteps):  # local (K, B, P)
+            if carried:
+                return _window_carry_run(
+                    local, w, w0, lay=lay, steps=steps, num_devices=Dr,
+                    kw={k: kw[k] for k in ("kind", "iterations", "scratch")},
+                    unroll=unroll, member_steps=msteps if hetero else None)
             state = megastep(local, i0, w0)
             if steps == 1:
                 return state

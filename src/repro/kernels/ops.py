@@ -23,6 +23,7 @@ from repro.kernels.ssd_scan import ssd_chunk_pallas
 from repro.kernels.taskbench_compute import taskbench_compute_pallas
 from repro.kernels.taskbench_step import (
     taskbench_step_boundary,
+    taskbench_step_carry,
     taskbench_step_interior,
     taskbench_step_pallas,
 )
@@ -61,6 +62,13 @@ def taskbench_step(
     """
     return taskbench_step_pallas(src, idx, wgt, act,
                                  interpret=_interpret(), **kw)
+
+
+def taskbench_carry(carry, wgt, *, offset: int, payload: int, **kw):
+    """One S=1 window step on a tiled, halo-extended carry.
+    See kernels.taskbench_step.taskbench_step_carry."""
+    return taskbench_step_carry(carry, wgt, offset=offset, payload=payload,
+                                interpret=_interpret(), **kw)
 
 
 def taskbench_interior(src, idx, wgt, act, *, depth: int, **kw):

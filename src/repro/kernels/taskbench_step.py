@@ -64,6 +64,13 @@ the boundary entry stacks both 3*depth-row edge buffers of all K members
 onto the member axis of ONE launch and returns the rows the next exchange
 sends. Both reuse the valid-span machinery unchanged; see DESIGN.md §6.
 
+Loop-carried S=1 window (``taskbench_step_carry``): the runtime's scanned
+S=1 halo loop keeps its state in the kernel's own tiled layout, owned rows
+at a tile-aligned offset between two aligned halo blocks, so a step pads,
+slices and concatenates nothing: the launch reads its window at that
+offset and writes the new owned rows at the same offset, and only the
+2*halo edge rows move between launches.
+
 Three combine strategies, selected statically:
 
   window  for halo-expressible dependence patterns (the pallas_step
@@ -402,6 +409,12 @@ def taskbench_step_pallas(
     """Fused Task Bench timestep(s) for K graphs.
 
     ``steps_per_launch=1`` (default): one timestep, (K, W, payload) out.
+    Under ``combine="window"`` src is the halo-extended block, owned row w
+    at src row w + halo; the operands are padded to the chip's tiles and
+    the output sliced back on every call. The runtime's scanned S=1 halo
+    loop does not use this form: it keeps its state in the tiled layout
+    and launches ``taskbench_step_carry`` on it; the per-step callers
+    (tuple and host-stepped ensembles, the row grid, gather/onehot) do.
     ``block_rows=0`` keeps each member's full width in one program (the
     fine-grain default — minimal grid overhead); set it to tile wide graphs
     so the (block_rows, payload) working set fits VMEM.
@@ -519,6 +532,95 @@ def taskbench_step_pallas(
     )(srcp, idxp, wgtp)
     with jax.named_scope("lane_slice"):
         return out[:, :W, :payload]
+
+
+def _window_carry_kernel(src_ref, wgt_ref, o_ref, *, kind, iterations,
+                         scratch, payload, offset):
+    """One S=1 window step on a tiled, halo-extended carry.
+
+    Owned row w sits at carry row ``offset + w`` and combines carry rows
+    ``[offset + w - halo .. offset + w + halo]``, each window a ref load
+    (any sublane offset lowers). The new owned rows land at the same
+    tile-aligned offset; the halo blocks pass through unchanged (the
+    caller rewrites their edge rows).
+    """
+    wgt = wgt_ref[0]  # (n, D): owned rows tile-padded, pad rows weigh 0
+    n, taps = wgt.shape
+    base = offset - (taps - 1) // 2
+    x = jnp.zeros((n, src_ref.shape[-1]), jnp.float32)
+    for j in range(taps):
+        win = src_ref[0, pl.ds(base + j, n), :].astype(jnp.float32)
+        x = x + win * wgt[:, j:j + 1]
+    new = _apply_body_padded(
+        x.astype(o_ref.dtype), kind=kind, iterations=iterations,
+        scratch=scratch, payload=payload,
+    )
+    if offset:
+        o_ref[0, :offset, :] = src_ref[0, :offset, :]
+        o_ref[0, offset + n:, :] = src_ref[0, offset + n:, :]
+    o_ref[0, offset:offset + n, :] = new
+
+
+@functools.partial(
+    jax.jit,
+    static_argnames=("offset", "payload", "kind", "iterations", "scratch",
+                     "interpret"),
+)
+def taskbench_step_carry(
+    carry: jax.Array,
+    wgt: jax.Array,
+    *,
+    offset: int,
+    payload: int,
+    kind: str = "compute_bound",
+    iterations: int = 16,
+    scratch: int = 2048,
+    interpret: bool = False,
+) -> jax.Array:
+    """One S=1 window timestep on a loop-carried, halo-extended state (the
+    runtime's S=1 halo loop; DESIGN.md §4).
+
+    ``carry`` (K, M, Pp) holds each member's owned rows at rows
+    ``[offset, offset + n)`` with ``halo = (D - 1) // 2`` neighbour rows
+    directly on each side; ``wgt`` (K, n, D) is the window weight table of
+    the owned rows, zero on rows past the true block. The caller chooses
+    the tiling: on the chip ``offset`` and ``n`` are multiples of 8 and Pp
+    of 128, so nothing here pads or slices, and ``payload`` is the true
+    width inside Pp (the memory body sweeps only that). Returns a new
+    carry of the same shape: the new owned rows at the same offset, the
+    rest passed through. The output does not alias ``carry``: on a v5e an
+    aliased launch took 1.4 µs longer at W = 4096, more than the
+    whole-state loop copy it saves, and a loop that runs two launches an
+    iteration needs neither (DESIGN.md §4).
+    """
+    if carry.ndim != 3 or wgt.ndim != 3 or wgt.shape[0] != carry.shape[0]:
+        raise ValueError(
+            f"expected (K, M, Pp)/(K, n, D) operands, got "
+            f"{carry.shape}/{wgt.shape}")
+    K, M, Pp = carry.shape
+    _, n, taps = wgt.shape
+    halo = (taps - 1) // 2
+    if taps % 2 != 1 or offset < halo or M < offset + n + halo:
+        raise ValueError(
+            f"carry rows {M} cannot hold {n} owned rows at offset {offset} "
+            f"with {halo} halo rows each side (window D = {taps})")
+    if not 0 < payload <= Pp:
+        raise ValueError(f"payload {payload} outside the carry's {Pp} lanes")
+    return pl.pallas_call(
+        functools.partial(
+            _window_carry_kernel, kind=kind, iterations=iterations,
+            scratch=scratch, payload=payload, offset=offset,
+        ),
+        grid=(K,),
+        in_specs=[
+            pl.BlockSpec((1, M, Pp), lambda k: (k, 0, 0)),
+            pl.BlockSpec((1, n, taps), lambda k: (k, 0, 0)),
+        ],
+        out_specs=pl.BlockSpec((1, M, Pp), lambda k: (k, 0, 0)),
+        out_shape=jax.ShapeDtypeStruct(carry.shape, carry.dtype),
+        interpret=interpret,
+        name="taskbench_step_s1",
+    )(carry, wgt)
 
 
 def taskbench_step_interior(

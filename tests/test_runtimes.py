@@ -5,6 +5,13 @@ graph (DESIGN.md §2: the backends differ ONLY in scheduling/communication
 strategy, never in dataflow). Single-device here; the multi-device versions
 run in test_distributed.py subprocesses.
 """
+import os
+import subprocess
+import sys
+import textwrap
+from pathlib import Path
+
+import jax.numpy as jnp
 import numpy as np
 import pytest
 
@@ -263,6 +270,110 @@ def test_pallas_step_auto_steps_per_launch():
     np.testing.assert_allclose(out, ref, rtol=1e-5, atol=1e-6)
     # auto picks a deep schedule for this tiny shape -> few launches
     assert rt.dispatches_per_run(g) < g.steps
+
+
+# --------------------- pallas_step S=1 halo loop on its tiled carry
+
+#: (pattern, radius, width, payload, body): every halo pattern, H in
+#: {0, 1, 2, 5}, widths 20 and 52 (not multiples of 8), payloads 64 and 130
+CARRY_CASES = [
+    ("stencil_1d", 1, 52, 64, "compute_bound"),
+    ("stencil_1d_periodic", 1, 52, 130, "compute_bound"),
+    ("dom", 1, 20, 64, "compute_bound"),
+    ("nearest", 2, 52, 64, "compute_bound"),
+    ("nearest", 5, 20, 130, "compute_bound"),
+    ("random_nearest", 2, 52, 130, "compute_bound"),
+    ("no_comm", 1, 20, 130, "compute_bound"),
+    ("trivial", 1, 52, 64, "compute_bound"),
+    ("stencil_1d", 1, 20, 130, "memory_bound"),
+    ("nearest", 2, 52, 64, "empty"),
+]
+#: the chip's (sublanes, lanes) tile, run in interpret mode
+CHIP_TILE = (8, 128)
+
+
+def _planted_init(width, payload, seed):
+    """A seeded state with an inf, a -inf and a NaN inside it: a value
+    from outside the window, or a padded row read at a nonzero weight,
+    shows as a changed class."""
+    x = np.array(initial_state(width, payload, seed))
+    rows = np.random.default_rng(seed).choice(width, 3, replace=False)
+    x[rows, [0, payload // 2, payload - 1]] = [np.inf, -np.inf, np.nan]
+    return x
+
+
+def _check_carry_parity(pattern, radius, width, payload, body, *,
+                        tile=None, devices=None):
+    """The S=1 window loop on its halo-extended carry (chip tiling or
+    interpret mode's (1, 1)) against the per-step extend path it
+    replaced, bit for bit."""
+    g = TaskGraph(steps=6, width=width, payload=payload, pattern=pattern,
+                  radius=radius, seed=5,
+                  kernel=KernelSpec(body, 4, scratch=2 * payload))
+    x = _planted_init(width, payload, 5)
+    rt = get_runtime("pallas_step", devices=devices)
+    out = np.asarray(rt._build_halo(g, tile=tile)(x))
+    ref = np.asarray(rt._build_halo_extend(g)(x))
+    np.testing.assert_array_equal(out, ref, err_msg=f"{g.describe()} {tile}")
+
+
+def _check_carry_parity_ensemble(hetero, *, tile=None, devices=None):
+    """A stacked K=4 ensemble at S=1 on the carry, each member bit for bit
+    against running alone on the per-step extend path."""
+    steps = (3, 6, 1, 5) if hetero else (6,) * 4
+    members = [
+        TaskGraph(steps=t, width=52, payload=64, pattern=p, radius=1,
+                  kernel=KernelSpec("compute_bound", 4), seed=k)
+        for k, (p, t) in enumerate(zip(
+            ("stencil_1d", "dom", "stencil_1d_periodic", "nearest"), steps))
+    ]
+    inits = [_planted_init(52, 64, k) for k in range(4)]
+    rt = get_runtime("pallas_step", devices=devices)
+    outs = rt._build_ensemble_stacked(GraphEnsemble(members), tile=tile)(
+        [jnp.asarray(x) for x in inits])
+    for k, (g, x, out) in enumerate(zip(members, inits, outs)):
+        np.testing.assert_array_equal(
+            np.asarray(out), np.asarray(rt._build_halo_extend(g)(x)),
+            err_msg=f"member {k} T={g.steps} {tile}")
+
+
+@pytest.mark.parametrize("tile", [None, CHIP_TILE], ids=["interp", "chip"])
+@pytest.mark.parametrize("case", CARRY_CASES,
+                         ids=[f"{c[0]}-r{c[1]}-w{c[2]}-p{c[3]}-{c[4]}"
+                              for c in CARRY_CASES])
+def test_pallas_step_s1_carry_bit_identical(case, tile):
+    _check_carry_parity(*case, tile=tile)
+
+
+@pytest.mark.parametrize("tile", [None, CHIP_TILE], ids=["interp", "chip"])
+@pytest.mark.parametrize("hetero", [False, True], ids=["uniform", "hetero"])
+def test_pallas_step_s1_carry_stacked_ensemble_bit_identical(hetero, tile):
+    _check_carry_parity_ensemble(hetero, tile=tile)
+
+
+@pytest.mark.parametrize("tile", [None, CHIP_TILE], ids=["interp", "chip"])
+def test_pallas_step_s1_carry_bit_identical_4_devices(tile):
+    """The same parity on 4 forced host devices (a subprocess): real ring
+    exchanges into the halo blocks, 13-row blocks, and a halo of 5 rows
+    past a 5-row block (the multi-hop exchange)."""
+    code = f"""
+        import sys, jax
+        sys.path.insert(0, {str(Path(__file__).parent)!r})
+        import test_runtimes as t
+        devs = jax.devices()[:4]
+        for case in t.CARRY_CASES + [("nearest", 5, 20, 64, "compute_bound")]:
+            t._check_carry_parity(*case, tile={tile!r}, devices=devs)
+        for hetero in (False, True):
+            t._check_carry_parity_ensemble(hetero, tile={tile!r}, devices=devs)
+        print("OK")
+    """
+    env = dict(os.environ, JAX_PLATFORMS="cpu",
+               XLA_FLAGS="--xla_force_host_platform_device_count=4",
+               PYTHONPATH=str(Path(__file__).parents[1] / "src"))
+    done = subprocess.run([sys.executable, "-c", textwrap.dedent(code)],
+                          capture_output=True, text=True, timeout=600,
+                          env=env)
+    assert done.returncode == 0 and "OK" in done.stdout, done.stderr[-4000:]
 
 
 # ----------------------------- pallas_step pipelined deep-halo exchange

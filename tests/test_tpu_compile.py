@@ -175,3 +175,55 @@ def test_kernel_instruction_names_match_the_trace_reducer(
     assert calls, f"{launch}: no Mosaic kernel in module"
     assert all(c.startswith(name) for c in calls), calls
     assert all(KERNEL.search(c) for c in calls), calls
+
+
+def _computation(text: str, name: str) -> str:
+    """The body of HLO computation ``name`` in a compiled module's text."""
+    m = re.search(r"^%" + re.escape(name) + r" .*?^}", text, re.M | re.S)
+    assert m, f"no computation {name}"
+    return m.group(0)
+
+
+def test_s1_halo_loop_touches_the_state_only_in_the_megakernel(
+        topo, no_compile_cache, monkeypatch):
+    """The S=1 halo program at the benchmark cell's shape (W 4096,
+    payload 64), lowered for one described v5e chip: the scanned loop
+    runs two steps an iteration and holds exactly one Mosaic launch,
+    ``taskbench_step_s1``, for each, and no concatenate, pad, slice or
+    copy as long as the state's rows — the state stays in the
+    megakernel's tiled, halo-extended layout, alternating between two
+    buffers, and only the halo's edge rows move around it."""
+    from repro.core import KernelSpec, TaskGraph, get_runtime
+    from repro.kernels import ops
+
+    real_jit = jax.jit
+    compiled = []
+
+    def lowering_jit(f, **kw):  # the built program, compiled, not run
+        return lambda *args: compiled.append(
+            real_jit(f, **kw).lower(*args).compile().as_text())
+
+    monkeypatch.setattr(ops, "_interpret", lambda: False)
+    monkeypatch.setattr(jax, "jit", lowering_jit)
+    monkeypatch.setattr(jax, "device_put", lambda x, sharding=None: _sds(
+        sharding, x.shape, x.dtype))
+    g = TaskGraph(steps=9, width=W, payload=PAYLOAD, pattern="stencil_1d",
+                  kernel=KernelSpec("compute_bound", 1))
+    rt = get_runtime("pallas_step", devices=topo.devices[:1])
+    rt._build_halo(g)(jnp.zeros((W, PAYLOAD), jnp.float32))
+    monkeypatch.undo()
+    (text,) = compiled
+
+    loops = re.findall(r"= \(.*\) while\(.*body=%([\w.-]+)", text)
+    assert len(loops) == 1, loops
+    body = _computation(text, loops[0])
+    for called in re.findall(r"calls=%([\w.-]+)", body):
+        body += "\n" + _computation(text, called)
+    calls = re.findall(r"%([\w.-]+) = \S+ custom-call\(.*"
+                       r'custom_call_target="tpu_custom_call"', body)
+    assert len(calls) == 2, calls
+    assert all(c.startswith("taskbench_step_s1") for c in calls), calls
+    moves = [(op, dims) for dims, op in re.findall(
+        r"= \w+\[([\d,]*)\]\S* (concatenate|pad|slice|copy)\(", body)
+        if any(int(d) >= W for d in dims.split(",") if d)]
+    assert not moves, moves
