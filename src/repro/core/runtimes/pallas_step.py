@@ -1195,7 +1195,9 @@ class PallasStepRuntime(_BspBase):
 
     # ------------------------------------------- stride / all-gather plans
 
-    def _stride_step_fns(self, graph: TaskGraph) -> Tuple[Callable, Callable]:
+    def _stride_step_fns(
+        self, graph: TaskGraph, *, tile: Optional[Tuple[int, int]] = None
+    ) -> Tuple[Callable, Callable]:
         """(t0, step) closures for one stride-plan (butterfly) member.
 
         ``step(s, o, t)`` runs timestep t: the period slot's pairing
@@ -1205,11 +1207,17 @@ class PallasStepRuntime(_BspBase):
         {p, partner} and runs the body. Tables are device-invariant
         (XOR structure is translation-invariant across blocks), so they
         ride as closures; ``o`` is an unused operand slot kept for
-        signature parity with the halo members in tuple ensembles."""
+        signature parity with the halo members in tuple ensembles.
+        Under the pair combine the in-block swap is the named scope
+        ``xor_swap`` and the [x | partner] stack ``pair_src``. ``tile``
+        (sublanes, lanes) overrides the launch's padding tile, which is
+        the chip's (8, 128) on the TPU and (1, 1) in interpret mode."""
         D = len(self.devices)
         B = self._block(graph)
         mode = self._plan_combine(PLAN_STRIDE)
         kw = self._kernel_kw(graph.kernel, combine=mode)
+        if tile is not None:
+            kw["_tile"] = tile
         impl = self._halo_impl()
         period = graph.period
         strides = _patterns.butterfly_slot_strides(graph)
@@ -1224,7 +1232,8 @@ class PallasStepRuntime(_BspBase):
             if mode == "pair":
                 if s < B:
                     def partner_of(local):
-                        return _xor_swap(local, s)
+                        with jax.named_scope("xor_swap"):
+                            return _xor_swap(local, s)
                 else:
                     bs = s // B
 
@@ -1234,8 +1243,9 @@ class PallasStepRuntime(_BspBase):
                         return p
 
                 def branch(local):
-                    src = jnp.concatenate(
-                        [local, partner_of(local)], axis=0)
+                    partner = partner_of(local)
+                    with jax.named_scope("pair_src"):
+                        src = jnp.concatenate([local, partner], axis=0)
                     return _kops.taskbench_step(
                         src[None], dummy_i[None], dummy_w[None], **kw)[0]
 
@@ -1266,7 +1276,8 @@ class PallasStepRuntime(_BspBase):
             # leaves its gather-free lowering (a gather here would be the
             # one Mosaic-unfriendly op on an otherwise portable path)
             def t0(s, o):
-                src = jnp.concatenate([s, s], axis=0)
+                with jax.named_scope("pair_src"):
+                    src = jnp.concatenate([s, s], axis=0)
                 return _kops.taskbench_step(
                     src[None], dummy_i[None], dummy_w[None], **kw)[0]
         else:

@@ -389,7 +389,7 @@ def _blocked_call(src, idx, wgt, act, *, kind, iterations, scratch,
     jax.jit,
     static_argnames=(
         "kind", "iterations", "scratch", "block_rows", "combine",
-        "steps_per_launch", "interpret",
+        "steps_per_launch", "interpret", "_tile",
     ),
 )
 def taskbench_step_pallas(
@@ -405,6 +405,7 @@ def taskbench_step_pallas(
     combine: str = "gather",
     steps_per_launch: int = 1,
     interpret: bool = False,
+    _tile: Tuple[int, int] | None = None,
 ) -> jax.Array:
     """Fused Task Bench timestep(s) for K graphs.
 
@@ -414,10 +415,14 @@ def taskbench_step_pallas(
     the output sliced back on every call. The runtime's scanned S=1 halo
     loop does not use this form: it keeps its state in the tiled layout
     and launches ``taskbench_step_carry`` on it; the per-step callers
-    (tuple and host-stepped ensembles, the row grid, gather/onehot) do.
+    (tuple and host-stepped ensembles, the row grid, gather/onehot, the
+    stride plan's pair combine) do.
     ``block_rows=0`` keeps each member's full width in one program (the
     fine-grain default — minimal grid overhead); set it to tile wide graphs
-    so the (block_rows, payload) working set fits VMEM.
+    so the (block_rows, payload) working set fits VMEM. The private
+    ``_tile`` (sublanes, lanes) overrides the padding tile, the chip's
+    (8, 128) or (1, 1) in interpret mode, so tests can run the chip's
+    layout in interpret mode.
 
     ``steps_per_launch=S > 1``: the temporal-blocked path (see module
     docstring) — square (K, M, *) operands on a deep-halo working buffer,
@@ -476,7 +481,7 @@ def taskbench_step_pallas(
     # interpreter has no tile constraints, so off-TPU the operands stay
     # unpadded — lane-padding there would double the per-step elementwise
     # work this kernel exists to minimize.
-    lane, sublane = (1, 1) if interpret else (LANE, SUBLANE)
+    sublane, lane = _tile or ((1, 1) if interpret else (SUBLANE, LANE))
     pad_p = (-payload) % lane
     block_rows = block_rows or W + (-W) % sublane
     block_rows = max(sublane, min(block_rows, W + (-W) % sublane))

@@ -6,11 +6,13 @@ strategy, never in dataflow). Single-device here; the multi-device versions
 run in test_distributed.py subprocesses.
 """
 import os
+import re
 import subprocess
 import sys
 import textwrap
 from pathlib import Path
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -600,6 +602,62 @@ def test_pallas_step_gather_transports_bit_identical():
         a = get_runtime("pallas_step").execute(g)
         b = get_runtime("pallas_step", halo_impl="ppermute").execute(g)
         assert np.array_equal(a, b), pattern
+
+
+# ------------------- pallas_step stride plan, one timestep at a time
+
+def stride_step_mismatches(width, payload, slots, *, tile=None, steps=1000):
+    """The stride plan's step (``_stride_step_fns``, one device) at each
+    timestep t of ``slots``, on a fresh planted state, against one
+    independent step in numpy: the mean of rows p and
+    p XOR 2^((t-1) mod log2 W), then the 4-iteration compute_bound body.
+    Returns {t: elements that differ bit for bit}; NaN matches NaN. A
+    1000-step output cannot show these partners: after one period every
+    column is constant across the rows."""
+    g = TaskGraph(steps=steps, width=width, payload=payload, pattern="fft",
+                  kernel=KernelSpec("compute_bound", 4))
+    rt = get_runtime("pallas_step", devices=jax.devices()[:1])
+    _, step = rt._stride_step_fns(g, tile=tile)
+    run = jax.jit(lambda x, t: step(x, (), t))
+    levels = int(np.log2(width))
+    rows = np.arange(width)
+    bad = {}
+    for t in slots:
+        x = _planted_init(width, payload, t)
+        got = np.asarray(run(jnp.asarray(x), jnp.int32(t)))
+        with np.errstate(invalid="ignore"):
+            y = (x + x[rows ^ (1 << ((t - 1) % levels))]) / np.float32(2)
+            for _ in range(4):
+                y = np.float32(0.5) * y + np.float32(0.1)
+        same = (got == y) | (np.isnan(got) & np.isnan(y))
+        bad[t] = int((~same).sum())
+    return bad
+
+
+@pytest.mark.parametrize("tile", [None, CHIP_TILE], ids=["interp", "chip"])
+def test_pallas_step_stride_step_partners_bit_identical(tile):
+    """fft at W=64 (L=6): every slot of two periods and the wrap (t = 1 to
+    13) and the last step of a 1000-step graph (t = 999), bit for bit."""
+    bad = stride_step_mismatches(64, 64, list(range(1, 14)) + [999],
+                                 tile=tile)
+    assert not any(bad.values()), bad
+
+
+def test_pallas_step_stride_scopes_reach_op_metadata():
+    """The stride plan's per-step ops carry the named scopes ``xor_swap``
+    (the in-block swap) and ``pair_src`` (the [x | partner] stack) into
+    the lowered module, in every branch of the step's switch and at t=0."""
+    g = graph("fft", steps=6)  # W=16: four levels, four branches
+    t0, step = get_runtime("pallas_step")._stride_step_fns(g)
+    text = jax.jit(lambda x, t: step(t0(x, ()), (), t)).lower(
+        jnp.zeros((16, 8), jnp.float32), jnp.int32(2)).as_text(
+            debug_info=True)
+    names = re.findall(r'loc\("([^"]+)"', text)
+    for b in range(4):
+        for scope in ("xor_swap", "pair_src"):
+            assert any(f"branch_{b}_fun/{scope}/" in n for n in names), (
+                b, scope)
+    assert any(n.startswith("jit(<lambda>)/pair_src/") for n in names)
 
 
 def test_pallas_step_mixed_plan_ensemble():

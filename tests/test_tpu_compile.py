@@ -184,6 +184,24 @@ def _computation(text: str, name: str) -> str:
     return m.group(0)
 
 
+def _with_callees(text: str, name: str) -> str:
+    """Computation ``name`` and every computation it calls, branches of a
+    conditional included."""
+    seen, todo, out = set(), [name], []
+    while todo:
+        n = todo.pop()
+        if n in seen:
+            continue
+        seen.add(n)
+        body = _computation(text, n)
+        out.append(body)
+        for ref in re.findall(
+                r"(?:calls=(%[\w.-]+)|branch_computations=\{([^}]*)\})",
+                body):
+            todo += re.findall(r"%([\w.-]+)", " ".join(ref))
+    return "\n".join(out)
+
+
 def test_s1_halo_loop_touches_the_state_only_in_the_megakernel(
         topo, no_compile_cache, monkeypatch):
     """The S=1 halo program at the benchmark cell's shape (W 4096,
@@ -216,9 +234,7 @@ def test_s1_halo_loop_touches_the_state_only_in_the_megakernel(
 
     loops = re.findall(r"= \(.*\) while\(.*body=%([\w.-]+)", text)
     assert len(loops) == 1, loops
-    body = _computation(text, loops[0])
-    for called in re.findall(r"calls=%([\w.-]+)", body):
-        body += "\n" + _computation(text, called)
+    body = _with_callees(text, loops[0])
     calls = re.findall(r"%([\w.-]+) = \S+ custom-call\(.*"
                        r'custom_call_target="tpu_custom_call"', body)
     assert len(calls) == 2, calls
@@ -227,3 +243,48 @@ def test_s1_halo_loop_touches_the_state_only_in_the_megakernel(
         r"= \w+\[([\d,]*)\]\S* (concatenate|pad|slice|copy)\(", body)
         if any(int(d) >= W for d in dims.split(",") if d)]
     assert not moves, moves
+
+
+def test_fft_stride_loop_launches_only_the_pair_megakernel(
+        topo, no_compile_cache, monkeypatch):
+    """The fft program at the stencil cell's shape (W 4096, payload 64,
+    1000 steps), built through ``pallas_step``'s default plan
+    dispatch and lowered for one described v5e chip: the stride plan at
+    S=1, whose scanned loop switches among the 12 levels' branches, each
+    launching one ``taskbench_step_s1`` custom call that the trace
+    reducer's ``KERNEL`` matches; the in-block swap and the [x | partner]
+    stack keep their named scopes in the compiled module."""
+    from repro.core import KernelSpec, TaskGraph, get_runtime
+    from repro.kernels import ops
+
+    real_jit = jax.jit
+    compiled = []
+
+    def lowering_jit(f, **kw):  # the built program, compiled, not run
+        return lambda *args: compiled.append(
+            real_jit(f, **kw).lower(*args).compile().as_text())
+
+    g = TaskGraph(steps=1000, width=W, payload=PAYLOAD, pattern="fft",
+                  kernel=KernelSpec("compute_bound", 1))
+    rt = get_runtime("pallas_step", devices=topo.devices[:1])
+    plan = rt._schedule_for_graph(g)
+    assert (plan.kind, plan.steps_per_launch) == ("stride", 1)
+    assert rt.dispatches_per_run(g) == 1000
+    monkeypatch.setattr(ops, "_interpret", lambda: False)
+    monkeypatch.setattr(jax, "jit", lowering_jit)
+    monkeypatch.setattr(jax, "device_put", lambda x, sharding=None: _sds(
+        sharding, x.shape, x.dtype))
+    rt.build(g)(jnp.zeros((W, PAYLOAD), jnp.float32))
+    monkeypatch.undo()
+    (text,) = compiled
+
+    loops = re.findall(r"= \(.*\) while\(.*body=%([\w.-]+)", text)
+    assert len(loops) == 1, loops
+    body = _with_callees(text, loops[0])
+    calls = re.findall(r"%([\w.-]+) = \S+ custom-call\(.*"
+                       r'custom_call_target="tpu_custom_call"', body)
+    assert len(calls) == 12, calls
+    assert all(c.startswith("taskbench_step_s1") for c in calls), calls
+    assert all(KERNEL.search(c) for c in calls), calls
+    for scope in ("xor_swap", "pair_src"):
+        assert re.search(r'op_name="[^"]*/' + scope + "/", body), scope
