@@ -74,7 +74,8 @@ every graph to one of three PLANS instead of refusing anything non-halo:
              [x | partner] halves with the gather-free "pair" mode —
              elementwise (a+b)*0.5, bit-identical to the fused oracle
              (gather/onehot stay selectable as ablations). One launch +
-             at most one collective per step; per-step by construction
+             at most one collective per step (a single graph runs its
+             period as straight-line levels); per-step by construction
              (temporal blocking a stride plan needs the XOR-subgroup
              closure of the launch window, which is the full gather — so
              EXPLICITLY blocked requests route to:)
@@ -1195,23 +1196,25 @@ class PallasStepRuntime(_BspBase):
 
     # ------------------------------------------- stride / all-gather plans
 
-    def _stride_step_fns(
+    def _stride_levels(
         self, graph: TaskGraph, *, tile: Optional[Tuple[int, int]] = None
-    ) -> Tuple[Callable, Callable]:
-        """(t0, step) closures for one stride-plan (butterfly) member.
+    ) -> Tuple[Callable, Tuple[Callable, ...], Tuple[int, ...]]:
+        """(t0, branches, slot_branch) for one stride-plan (butterfly)
+        member: the single definition of a level, which both the
+        ensemble step's switch and the single-graph straight-line loop
+        call.
 
-        ``step(s, o, t)`` runs timestep t: the period slot's pairing
-        distance selects a branch — in-block strides gather locally,
-        block strides first XOR-permute the partner block in
-        (`_halo.exchange_stride`) — and one megakernel launch combines
-        {p, partner} and runs the body. Tables are device-invariant
-        (XOR structure is translation-invariant across blocks), so they
-        ride as closures; ``o`` is an unused operand slot kept for
-        signature parity with the halo members in tuple ensembles.
-        Under the pair combine the in-block swap is the named scope
-        ``xor_swap`` and the [x | partner] stack ``pair_src``. ``tile``
-        (sublanes, lanes) overrides the launch's padding tile, which is
-        the chip's (8, 128) on the TPU and (1, 1) in interpret mode."""
+        ``branches[j](s)`` runs one level at the j-th distinct pairing
+        distance — in-block strides gather locally, block strides first
+        XOR-permute the partner block in (`_halo.exchange_stride`) — and
+        one megakernel launch combines {p, partner} and runs the body;
+        period slot i runs ``branches[slot_branch[i]]``. Tables are
+        device-invariant (XOR structure is translation-invariant across
+        blocks), so they ride as closures. Under the pair combine the
+        in-block swap is the named scope ``xor_swap`` and the
+        [x | partner] stack ``pair_src``. ``tile`` (sublanes, lanes)
+        overrides the launch's padding tile, which is the chip's (8, 128)
+        on the TPU and (1, 1) in interpret mode."""
         D = len(self.devices)
         B = self._block(graph)
         mode = self._plan_combine(PLAN_STRIDE)
@@ -1219,10 +1222,8 @@ class PallasStepRuntime(_BspBase):
         if tile is not None:
             kw["_tile"] = tile
         impl = self._halo_impl()
-        period = graph.period
         strides = _patterns.butterfly_slot_strides(graph)
         distinct = sorted(set(strides))
-        bmap = jnp.asarray([distinct.index(s) for s in strides], jnp.int32)
         # pair mode's idx/wgt are kernel-side dummies (wgt's row count
         # declares the output width); table modes carry real slot tables
         dummy_i = jnp.zeros((1, 1), jnp.int32)
@@ -1267,7 +1268,8 @@ class PallasStepRuntime(_BspBase):
                         src[None], idx[None], wgt[None], **kw)[0]
             return branch
 
-        branches = [make_branch(s) for s in distinct]
+        branches = tuple(make_branch(s) for s in distinct)
+        slot_branch = tuple(distinct.index(s) for s in strides)
         i0, w0 = _self_tables(B)
 
         if mode == "pair":
@@ -1285,10 +1287,27 @@ class PallasStepRuntime(_BspBase):
                 return _kops.taskbench_step(
                     s[None], i0[None], w0[None], **kw)[0]
 
+        return t0, branches, slot_branch
+
+    def _stride_step_fns(
+        self, graph: TaskGraph, *, tile: Optional[Tuple[int, int]] = None
+    ) -> Tuple[Callable, Callable]:
+        """(t0, step) closures for one stride-plan member of a tuple
+        ensemble, whose cadence is shared step by step with halo members.
+
+        ``step(s, o, t)`` runs timestep t: a ``lax.switch`` on the period
+        slot (t-1) mod period picks the level (`_stride_levels`); ``o``
+        is an unused operand slot kept for signature parity with the halo
+        members. A single graph runs its levels straight-line instead
+        (`_build_plan_stepper`)."""
+        t0, branches, slot_branch = self._stride_levels(graph, tile=tile)
         if len(branches) == 1:
             def step(s, o, t):
                 return branches[0](s)
         else:
+            period = graph.period
+            bmap = jnp.asarray(slot_branch, jnp.int32)
+
             def step(s, o, t):
                 slot = jax.lax.rem(t - 1, period)
                 return jax.lax.switch(bmap[slot], branches, s)
@@ -1395,30 +1414,68 @@ class PallasStepRuntime(_BspBase):
             return self._stride_step_fns(graph)
         return self._allgather_step_fns(graph)
 
-    def _build_plan_stepper(self, graph: TaskGraph, plan: str) -> Callable:
-        """Single-graph per-step scan for the stride / all-gather plans:
-        one megakernel launch (plus at most one collective) per timestep,
+    def _build_plan_stepper(
+        self, graph: TaskGraph, plan: str, *,
+        tile: Optional[Tuple[int, int]] = None
+    ) -> Callable:
+        """Single-graph scan for the stride / all-gather plans: one
+        megakernel launch (plus at most one collective) per timestep,
         whole loop in one jit — the same dispatch shape as the halo S=1
-        path, with the plan's own exchange."""
+        path, with the plan's own exchange.
+
+        The stride plan runs its period straight-line: with P the period
+        and (q, r) = divmod(T-1, P), q scan trips each call levels
+        0..P-1 in order, each a direct call of its level
+        (`_stride_levels`) at a static stride, then levels 0..r-1 run
+        after the scan. No switch and no traced slot index: compiled for
+        the TPU, a switch returns each level's result through HBM and
+        copies it back to VMEM every step, while straight-line levels
+        keep the state in VMEM. ``unroll`` counts scan trips: periods for the
+        stride plan, steps for the all-gather plan. The
+        ``pallas_step.program`` span carries the stride plan's ``period``,
+        ``period_trips`` (q) and ``remainder_levels`` (r). ``tile`` as in
+        `_stride_levels`."""
         unroll = int(self.options.get("unroll", 1))
         mesh = self._mesh()
         T = graph.steps
+        loop_attrs = {}
         with layer_span(self.tracer, "pallas_step.operands"):
-            t0, step = self._plan_step_fns(graph, plan)
+            if plan == PLAN_STRIDE:
+                t0, branches, slot_branch = self._stride_levels(
+                    graph, tile=tile)
+                levels = [branches[b] for b in slot_branch]
+                trips, rem = divmod(T - 1, len(levels))
+                loop_attrs = dict(period=len(levels), period_trips=trips,
+                                  remainder_levels=rem)
+
+                def run_steps(state):
+                    def body(s, _):
+                        for level in levels:
+                            s = level(s)
+                        return s, None
+
+                    if trips:
+                        state, _ = jax.lax.scan(body, state, None,
+                                                length=trips, unroll=unroll)
+                    for level in levels[:rem]:
+                        state = level(state)
+                    return state
+            else:
+                t0, step = self._allgather_step_fns(graph)
+
+                def run_steps(state):
+                    def body(s, t):
+                        return step(s, (), t), None
+
+                    state, _ = jax.lax.scan(
+                        body, state, jnp.arange(1, T), unroll=unroll)
+                    return state
 
         def local_run(local):
             state = t0(local, ())
-            if T == 1:
-                return state
+            return state if T == 1 else run_steps(state)
 
-            def body(s, t):
-                return step(s, (), t), None
-
-            state, _ = jax.lax.scan(
-                body, state, jnp.arange(1, T), unroll=unroll)
-            return state
-
-        with layer_span(self.tracer, "pallas_step.program"):
+        with layer_span(self.tracer, "pallas_step.program", **loop_attrs):
             fn = jax.jit(
                 shard_map(local_run, mesh=mesh, check_vma=False,
                           in_specs=P(AXIS), out_specs=P(AXIS)))

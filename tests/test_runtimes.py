@@ -643,6 +643,75 @@ def test_pallas_step_stride_step_partners_bit_identical(tile):
     assert not any(bad.values()), bad
 
 
+STRIDE_LOOP_CASES = [("fft", 64), ("tree", 16)]  # periods 6 and 8
+
+
+@pytest.mark.parametrize("tile", [None, CHIP_TILE], ids=["interp", "chip"])
+@pytest.mark.parametrize("pattern,width", STRIDE_LOOP_CASES,
+                         ids=[f"{p}-w{w}" for p, w in STRIDE_LOOP_CASES])
+def test_pallas_step_stride_loop_bit_identical_every_remainder(
+        pattern, width, tile):
+    """The single-graph stride program (q straight-line periods in the
+    scan, then the remainder's r levels) against ``fused``, bit for bit,
+    at every T from 1 to 2P+2: the empty scan (T-1 < P), every remainder
+    (T-1) mod P, and the wrap into a second and third period."""
+    rt = get_runtime("pallas_step", devices=jax.devices()[:1])
+    fused = get_runtime("fused")
+    period = graph(pattern, width=width).period
+    for T in range(1, 2 * period + 3):
+        g = TaskGraph(steps=T, width=width, payload=8, pattern=pattern,
+                      kernel=KernelSpec("compute_bound", 2))
+        assert rt.plan_for(g)[0] == "stride"
+        x = jnp.asarray(_planted_init(width, 8, T))
+        out = np.asarray(rt._build_plan_stepper(g, "stride", tile=tile)(x))
+        np.testing.assert_array_equal(out, fused.execute(g, x),
+                                      err_msg=f"{pattern} T={T} {tile}")
+
+
+@pytest.fixture
+def non_contracting_body(monkeypatch):
+    """The FMA body with A = B = 1: each iteration adds 1, so no state
+    contracts and a dropped or repeated step shows in every element. The
+    constants are read at trace time, so the jit caches are cleared on
+    both sides of the patch."""
+    from repro.kernels import bodies
+
+    jax.clear_caches()
+    monkeypatch.setattr(bodies, "FMA_A", 1.0)
+    monkeypatch.setattr(bodies, "FMA_B", 1.0)
+    yield
+    monkeypatch.undo()
+    jax.clear_caches()
+
+
+@pytest.mark.parametrize("steps,remainder", [(1000, 3), (997, 0)])
+def test_pallas_step_stride_loop_step_count(non_contracting_body, steps,
+                                            remainder):
+    """fft at W 64 (P 6), grain 1, under a body that adds 1 a step: the
+    built program's output equals ``fused`` and an independent numpy
+    loop exactly, so it ran every one of the T steps; the
+    ``pallas_step.program`` span reports the q scan trips and the r
+    remainder levels it ran them as."""
+    W, payload, levels = 64, 8, 6
+    g = TaskGraph(steps=steps, width=W, payload=payload, pattern="fft",
+                  kernel=KernelSpec("compute_bound", 1))
+    x = np.random.default_rng(steps).random((W, payload), dtype=np.float32)
+    rt = get_runtime("pallas_step", devices=jax.devices()[:1], trace=True)
+    out = np.asarray(rt.build(g)(jnp.asarray(x)))
+    attrs = {sp.name: sp for sp in rt.tracer.spans}[
+        "pallas_step.program"].attrs
+    assert (attrs["period"], attrs["period_trips"],
+            attrs["remainder_levels"]) == (levels, 166, remainder)
+    rows = np.arange(W)
+    y = x + np.float32(1)  # t=0: the body alone
+    for t in range(1, steps):
+        y = (y + y[rows ^ (1 << ((t - 1) % levels))]) / np.float32(2)
+        y = y + np.float32(1)
+    np.testing.assert_array_equal(out, y)
+    np.testing.assert_array_equal(
+        out, get_runtime("fused").execute(g, jnp.asarray(x)))
+
+
 def test_pallas_step_stride_scopes_reach_op_metadata():
     """The stride plan's per-step ops carry the named scopes ``xor_swap``
     (the in-block swap) and ``pair_src`` (the [x | partner] stack) into

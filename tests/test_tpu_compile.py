@@ -250,10 +250,13 @@ def test_fft_stride_loop_launches_only_the_pair_megakernel(
     """The fft program at the stencil cell's shape (W 4096, payload 64,
     1000 steps), built through ``pallas_step``'s default plan
     dispatch and lowered for one described v5e chip: the stride plan at
-    S=1, whose scanned loop switches among the 12 levels' branches, each
-    launching one ``taskbench_step_s1`` custom call that the trace
-    reducer's ``KERNEL`` matches; the in-block swap and the [x | partner]
-    stack keep their named scopes in the compiled module."""
+    S=1, whose scanned loop runs the 12-level period straight-line, 12
+    ``taskbench_step_s1`` custom calls that the trace reducer's
+    ``KERNEL`` matches, with no ``conditional`` and no copy of the state
+    (a switch would return each level's result through HBM and copy it
+    back every step); the module's other launches are t=0 and the 3
+    remainder levels (999 = 83 x 12 + 3). The in-block swap and the
+    [x | partner] stack keep their named scopes in the compiled module."""
     from repro.core import KernelSpec, TaskGraph, get_runtime
     from repro.kernels import ops
 
@@ -278,6 +281,9 @@ def test_fft_stride_loop_launches_only_the_pair_megakernel(
     monkeypatch.undo()
     (text,) = compiled
 
+    assert " conditional(" not in text
+    launches = re.findall(r'custom_call_target="tpu_custom_call"', text)
+    assert len(launches) == 1 + 12 + 3, len(launches)
     loops = re.findall(r"= \(.*\) while\(.*body=%([\w.-]+)", text)
     assert len(loops) == 1, loops
     body = _with_callees(text, loops[0])
@@ -286,5 +292,9 @@ def test_fft_stride_loop_launches_only_the_pair_megakernel(
     assert len(calls) == 12, calls
     assert all(c.startswith("taskbench_step_s1") for c in calls), calls
     assert all(KERNEL.search(c) for c in calls), calls
+    copies = [dims for dims in re.findall(
+        r"= \w+\[([\d,]*)\]\S* copy\(", body)
+        if any(int(d) >= W for d in dims.split(",") if d)]
+    assert not copies, copies
     for scope in ("xor_swap", "pair_src"):
         assert re.search(r'op_name="[^"]*/' + scope + "/", body), scope
