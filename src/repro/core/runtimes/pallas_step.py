@@ -41,8 +41,12 @@ edge/wrap/empty branches.
 Ensembles: a stackable ensemble with a uniform KernelSpec runs ALL K
 members' combines and bodies in the SAME launch (the megakernel's leading K
 axis); one deep ring exchange moves every member's halos for S steps at
-once. Mixed-spec or ragged-shape ensembles fall back to one launch per
-member inside the same jitted scan.
+once. The halo plan has one builder for this, `_build_halo_stacked`, and a
+single graph is its one-member case; each launch form (`_window_carry_run`,
+`_extend_launch`, `_serial_launch`, `_pipelined_launch`) is written once at
+module level and shared with the host-stepped and tuple paths. Mixed-spec or
+ragged-shape ensembles fall back to one launch per member inside the same
+jitted scan.
 
 Double-buffered deep-halo pipeline (``pipeline=True``, the default): with
 blocking alone every deep exchange still sits serially between launches, so
@@ -98,6 +102,7 @@ block_rows, unroll, gather_width_cap=int, halo_impl="xla"|"ppermute".
 """
 from __future__ import annotations
 
+import functools
 from typing import Callable, List, NamedTuple, Optional, Sequence, Tuple
 
 import jax
@@ -499,6 +504,29 @@ def _phase_tables(idx: jax.Array, wgt: jax.Array, depth: int,
         i_int = _rebase_rows(rel_int, row_axis=1)
         i_bnd = _rebase_rows(rel_bnd, row_axis=1)
     return _PhaseTables(i_int, w_int, i_bnd, w_bnd)
+
+
+def _extend_launch(s: jax.Array, i: jax.Array, w: jax.Array, *, halo: int,
+                   num_devices: int, kw: dict) -> jax.Array:
+    """One S=1 step on (K, B, payload) state off the carry: ring-extend
+    by ``halo`` rows each side, then one megakernel launch over the
+    [halo | block | halo] rows."""
+    return _kops.taskbench_step(
+        _extend_state(s, halo, num_devices, row_axis=1), i, w, **kw)
+
+
+def _serial_launch(s: jax.Array, iext: jax.Array, wext: jax.Array,
+                   a: jax.Array, *, depth: int, num_devices: int,
+                   kwb: dict) -> jax.Array:
+    """One serial blocked launch on (K, B, payload) state: the deep
+    exchange of ``depth`` rows each side, one S-step launch over the
+    extended buffer (tables deep-exchanged once, `_extend_tables`), and
+    the owned rows sliced back out."""
+    B = s.shape[1]
+    nf = _kops.taskbench_step(
+        _extend_state(s, depth, num_devices, row_axis=1), iext, wext, a,
+        **kwb)
+    return jax.lax.slice_in_dim(nf, depth, depth + B, axis=1)
 
 
 def _pipelined_launch(s, hl, hr, a, ph: _PhaseTables, depth: int,
@@ -1029,171 +1057,179 @@ class PallasStepRuntime(_BspBase):
                 fn = self._build_plan_stepper(graph, plan.kind)
             elif plan.kind == PLAN_ALLGATHER:
                 fn = self._build_allgather_blocked(graph, S)
-            elif S > 1:
-                fn = self._build_blocked(graph, S)
             else:
-                fn = self._build_halo(graph)
+                fn = self._build_halo_stacked(graph, S)
         return _with_call_spans(fn, tr)
 
-    def _build_halo(self, graph: TaskGraph, *,
-                    tile: Optional[Tuple[int, int]] = None) -> Callable:
-        """S=1 halo plan, the whole loop in one scanned program.
+    # ------------------------------------------------------------ halo plan
 
-        Under the window combine the state rides the scan in the
-        megakernel's own layout (``_CarryLayout``: tile-padded rows and
-        lanes, an aligned halo block each side), padded once before the
-        scan and sliced once after it; a step is one megakernel launch
-        on the carry plus the ring exchange of the 2*H edge rows
-        written into its halo blocks (none at H = 0). ``tile`` overrides
-        the (sublanes, lanes) tile, so the chip's (8, 128) layout also
-        runs in interpret mode. The gather/onehot combines and a
-        ``block_rows`` row grid keep the per-step extend of
-        ``_build_halo_extend``."""
-        if self._combine_mode() != "window" or self.options.get("block_rows"):
-            return self._build_halo_extend(graph)
-        H = _patterns.halo_radius(graph)
+    def _build_halo_stacked(
+        self, unit: TaskGraph | GraphEnsemble, S: int, *,
+        tile: Optional[Tuple[int, int]] = None,
+        carry: Optional[bool] = None,
+    ) -> Callable:
+        """The halo plan's one builder, for a single graph and for a
+        stacked ensemble alike: the loop over a (K, B, payload) local
+        state in one scanned program, every launch running all K members'
+        combines and bodies, one ring exchange moving every member's
+        halos.
+
+        ``unit`` is a TaskGraph or a GraphEnsemble. A single graph is the
+        one-member case on the 1D row mesh: its program takes and returns
+        the (W, payload) state sharded ``P(AXIS)``, adding and dropping
+        the member axis inside the shard_map. An ensemble's program takes
+        the stacked (K, W, payload) state; with ``member_shards`` Dk > 1
+        it runs over the 2D (row, member) mesh, each device holding a
+        (K/Dk, W/Dr, payload) slice, and every halo exchange still names
+        AXIS, spanning only its Dr-device row subgroup. Outputs are
+        bit-identical either way (same per-row arithmetic, only ownership
+        moves).
+
+        The loop's form is chosen here, once:
+
+          S = 1, carry  window combine, no ``block_rows``: the state rides
+                        the scan in the megakernel's own layout
+                        (`_window_carry_run`);
+          S = 1, extend gather/onehot or a row grid: per step a ring
+                        extend and one launch (`_extend_launch`);
+          S > 1         pipelined when `_pipeline_active` (boundary and
+                        interior phases, the next exchange under the
+                        interior: `_pipelined_launch`), else serial
+                        (`_serial_launch`).
+
+        The program reads only its branch's operands, and freezes member
+        k once t reaches its own T (``member_steps``, or the act
+        schedule at S > 1). ``tile`` overrides the carry's (sublanes,
+        lanes) tile, so the chip's (8, 128) layout also runs in interpret
+        mode; ``carry`` forces the S = 1 branch (tests)."""
+        single = isinstance(unit, TaskGraph)
+        ens = GraphEnsemble([unit]) if single else unit
+        members = ens.members
+        K = len(members)
         unroll = int(self.options.get("unroll", 1))
-        mesh = self._mesh()
         D = len(self.devices)
-        spec = graph.kernel
-        kw = dict(kind=spec.kind, iterations=spec.iterations,
-                  scratch=spec.scratch)
-        lay = _carry_layout(self._block(graph), H, graph.payload, tile)
+        mesh, dk, Dr = ((self._mesh(), 1, D) if single
+                        else self._stacked_mesh(ens))
+        H = max(_patterns.halo_radius(g) for g in members)
+        B = members[0].width // Dr
+        payload = members[0].payload
+        steps = ens.steps
+        kw = self._kernel_kw(members[0].kernel)
+        mode = kw["combine"]
+        if carry is None:
+            carry = mode == "window" and "block_rows" not in kw
+        xspec = (P(AXIS) if single else
+                 P(MEMBER_AXIS, AXIS) if dk > 1 else P(None, AXIS))
         with layer_span(self.tracer, "pallas_step.operands"):
-            _, wgt, _, wgt0 = self._operands(graph, H)
+            operands = self._blocked_operands if S > 1 else self._operands
+            ops4 = [operands(g, H, block=B) for g in members]
+            idx, wgt, idx0, wgt0 = ops4[0] if single else _stack_operands(ops4)
+            extra, espec = (), ()
+            if S > 1:
+                # (L, K, S): the member axis shards its K slices alongside
+                # the state, so each device masks only the members it owns
+                extra = (_act_schedule(ens.member_steps, steps, S),)
+                espec = (P(None, MEMBER_AXIS) if dk > 1 else P(),)
+            elif ens.heterogeneous_steps:
+                extra = (np.asarray(ens.member_steps, np.int32),)
+                espec = (P(MEMBER_AXIS) if dk > 1 else P(),)
 
-        def local_run(local, w, w0):  # (B, P), (B, 2H+1), (B, 1)
-            return _window_carry_run(
-                local[None], w[None], w0[None], lay=lay, steps=graph.steps,
-                num_devices=D, kw=kw, unroll=unroll)[0]
+        if S == 1 and carry:
+            tables = (wgt, wgt0)
+            lay = _carry_layout(B, H, payload, tile)
+            ckw = {k: kw[k] for k in ("kind", "iterations", "scratch")}
 
-        with layer_span(self.tracer, "pallas_step.program"):
-            fn = jax.jit(
-                shard_map(
-                    local_run, mesh=mesh, check_vma=False,
-                    in_specs=(P(AXIS),) * 3, out_specs=P(AXIS),
-                )
-            )
-        sh = NamedSharding(mesh, P(AXIS))
-        consts = tuple(
-            jax.device_put(jnp.asarray(a), sh) for a in (wgt, wgt0)
-        )
-        return lambda init: fn(jax.device_put(init, sh), *consts)
+            def local_run(local, w, w0, msteps=None):
+                return _window_carry_run(
+                    local, w, w0, lay=lay, steps=steps, num_devices=Dr,
+                    kw=ckw, unroll=unroll, member_steps=msteps)
+        elif S == 1:
+            tables = (idx, wgt, idx0, wgt0)
 
-    def _build_halo_extend(self, graph: TaskGraph) -> Callable:
-        """S=1 halo plan under the gather/onehot combines or a row grid:
-        per step one ring extend + one megakernel launch on the
-        [halo | block | halo] rows, the whole loop in one scanned
-        program."""
-        H = _patterns.halo_radius(graph)
-        unroll = int(self.options.get("unroll", 1))
-        mesh = self._mesh()
-        D = len(self.devices)
-        kw = self._kernel_kw(graph.kernel)
-        with layer_span(self.tracer, "pallas_step.operands"):
-            idx, wgt, idx0, wgt0 = self._operands(graph, H)
+            def local_run(local, i, w, i0, w0, msteps=None):
+                state = _kops.taskbench_step(local, i0, w0, **kw)  # t=0
+                if steps == 1:
+                    return state
 
-        def megastep(ext_src, i, w):  # (B|B+2H, P), (B, D'), (B, D')
-            return _kops.taskbench_step(ext_src[None], i[None], w[None], **kw)[0]
+                def body(s, t):
+                    nxt = _extend_launch(s, i, w, halo=H, num_devices=Dr,
+                                         kw=kw)
+                    if msteps is not None:
+                        nxt = jnp.where((t < msteps)[:, None, None], nxt, s)
+                    return nxt, None
 
-        def local_run(local, i, w, i0, w0):  # all (B, ...) per device
-            state = megastep(local, i0, w0)  # t=0: body only
-            if graph.steps == 1:
+                ts = None if msteps is None else jnp.arange(1, steps)
+                state, _ = jax.lax.scan(body, state, ts, length=steps - 1,
+                                        unroll=unroll)
+                return state
+        else:
+            tables = (idx, wgt, idx0, wgt0)
+            depth = S * H
+            kwb = dict(kw, steps_per_launch=S)
+            kwb.pop("block_rows", None)  # blocked path: one program a member
+            pipelined = self._pipeline_active(B, S, H, payload)
+            impl = self._halo_impl()
+
+            def local_run(local, i, w, i0, w0, act_seq):  # (L, K, S) acts
+                state = _kops.taskbench_step(local, i0, w0, **kw)  # t=0
+                if steps == 1:
+                    return state
+                if pipelined:
+                    ph = _phase_tables(i, w, depth, Dr, mode)
+                    h = _prologue_exchange(state, depth, Dr, impl)
+
+                    def pbody(c, a):
+                        s, hl, hr = c
+                        s2, h2 = _pipelined_launch(
+                            s, hl, hr, a, ph, depth, Dr, kwb, impl)
+                        return (s2, h2.recv_left, h2.recv_right), None
+
+                    (state, _, _), _ = jax.lax.scan(
+                        pbody, (state, h.recv_left, h.recv_right), act_seq,
+                        unroll=unroll)
+                    return state
+                # the per-row operand tables are deep-exchanged ONCE: every
+                # working row then owns its exact (edge-clipped) weights
+                iext, wext = _extend_tables(i, w, depth, Dr, mode, row_axis=1)
+
+                def body(s, a):
+                    return _serial_launch(s, iext, wext, a, depth=depth,
+                                          num_devices=Dr, kwb=kwb), None
+
+                state, _ = jax.lax.scan(body, state, act_seq, unroll=unroll)
                 return state
 
-            def body(s, _):
-                return megastep(_extend_state(s, H, D), i, w), None
+        n = len(tables)
+        if single:  # the member axis is added and dropped inside
+            run_stacked = local_run
 
-            state, _ = jax.lax.scan(
-                body, state, None, length=graph.steps - 1, unroll=unroll
-            )
-            return state
-
-        with layer_span(self.tracer, "pallas_step.program"):
-            fn = jax.jit(
-                shard_map(
-                    local_run, mesh=mesh, check_vma=False,
-                    in_specs=(P(AXIS),) * 5, out_specs=P(AXIS),
-                )
-            )
-        sh = NamedSharding(mesh, P(AXIS))
-        consts = tuple(
-            jax.device_put(jnp.asarray(a), sh) for a in (idx, wgt, idx0, wgt0)
-        )
-        return lambda init: fn(jax.device_put(init, sh), *consts)
-
-    def _build_blocked(self, graph: TaskGraph, S: int) -> Callable:
-        """ceil((T-1)/S) launches: one deep exchange + one S-step kernel
-        per launch instead of one exchange + one launch per step. When the
-        pipeline applies (DESIGN.md §6) each launch splits into boundary +
-        interior phases and the next launch's exchange rides under the
-        interior; otherwise the exchange sits serially before the launch.
-        """
-        unroll = int(self.options.get("unroll", 1))
-        mesh = self._mesh()
-        D = len(self.devices)
-        H = _patterns.halo_radius(graph)
-        depth = S * H
-        mode = self._combine_mode()
-        kw0 = self._kernel_kw(graph.kernel)
-        kwb = dict(kw0, steps_per_launch=S)
-        kwb.pop("block_rows", None)  # blocked path: one program per member
-        with layer_span(self.tracer, "pallas_step.operands"):
-            idx, wgt, idx0, wgt0 = self._blocked_operands(graph, H)
-            acts = _act_schedule((graph.steps,), graph.steps, S)[:, 0]  # (L, S)
-        T = graph.steps
-        pipelined = self._pipeline_active(self._block(graph), S, H,
-                                          graph.payload)
-        impl = self._halo_impl()
-
-        def local_run(local, i, w, i0, w0, act_seq):
-            state = _kops.taskbench_step(
-                local[None], i0[None], w0[None], **kw0)[0]  # t=0: body only
-            if T == 1:
-                return state
-            B = local.shape[0]
-            if pipelined:
-                ph = _phase_tables(i[None], w[None], depth, D, mode)
-                h = _prologue_exchange(state[None], depth, D, impl)
-
-                def pbody(carry, a):  # a: (S,) per-depth activity
-                    s, hl, hr = carry
-                    s2, h2 = _pipelined_launch(
-                        s, hl, hr, a[None], ph, depth, D, kwb, impl)
-                    return (s2, h2.recv_left, h2.recv_right), None
-
-                (state3, _, _), _ = jax.lax.scan(
-                    pbody, (state[None], h.recv_left, h.recv_right),
-                    act_seq, unroll=unroll)
-                return state3[0]
-
-            # the per-row operand tables are deep-exchanged ONCE: every
-            # working row then owns its exact (edge-clipped) weights
-            iext, wext = _extend_tables(i, w, depth, D, mode)
-
-            def body(s, a):  # a: (S,) per-depth activity
-                ext = _extend_state(s, depth, D)
-                nf = _kops.taskbench_step(
-                    ext[None], iext[None], wext[None], a[None], **kwb)[0]
-                return jax.lax.slice_in_dim(nf, depth, depth + B, axis=0), None
-
-            state, _ = jax.lax.scan(body, state, act_seq, unroll=unroll)
-            return state
+            # wraps keeps run_stacked's parameter names, which name the
+            # compiled program's parameters
+            @functools.wraps(run_stacked)
+            def local_run(local, *ops):
+                return run_stacked(local[None], *(o[None] for o in ops[:n]),
+                                   *ops[n:])[0]
 
         with layer_span(self.tracer, "pallas_step.program"):
             fn = jax.jit(
                 shard_map(
                     local_run, mesh=mesh, check_vma=False,
-                    in_specs=(P(AXIS),) * 5 + (P(),), out_specs=P(AXIS),
+                    in_specs=(xspec,) * (1 + n) + espec, out_specs=xspec,
                 )
             )
-        sh = NamedSharding(mesh, P(AXIS))
-        rep = NamedSharding(mesh, P())
+        sh = NamedSharding(mesh, xspec)
         consts = tuple(
-            jax.device_put(jnp.asarray(a), sh) for a in (idx, wgt, idx0, wgt0)
-        ) + (jax.device_put(jnp.asarray(acts), rep),)
-        return lambda init: fn(jax.device_put(init, sh), *consts)
+            jax.device_put(jnp.asarray(a), sh) for a in tables
+        ) + tuple(jax.device_put(jnp.asarray(a), NamedSharding(mesh, s))
+                  for a, s in zip(extra, espec))
+        if single:
+            return lambda init: fn(jax.device_put(init, sh), *consts)
 
+        def run(inits):
+            out = fn(jax.device_put(jnp.stack(inits), sh), *consts)
+            return tuple(out[k] for k in range(K))
+
+        return run
     # ------------------------------------------- stride / all-gather plans
 
     def _stride_levels(
@@ -1408,11 +1444,32 @@ class PallasStepRuntime(_BspBase):
 
         return t0, step
 
-    def _plan_step_fns(self, graph: TaskGraph,
-                       plan: str) -> Tuple[Callable, Callable]:
+    def _member_step_fns(self, graph: TaskGraph,
+                         plan: str) -> Tuple[tuple, Callable, Callable]:
+        """(operands, t0, step) for one member of a tuple ensemble on its
+        own plan, as `_build_ensemble_tuple` and `_launch_plan_stepwise`
+        run it: ``t0(s, o)`` is the body-only launch and ``step(s, o,
+        t)`` timestep t, on the member's (B, payload) block with ``o``
+        its sharded operands. A halo member carries its host tables
+        (`_operands`) and steps by `_extend_launch`; stride and
+        all-gather members carry closure tables and an empty slot."""
         if plan == PLAN_STRIDE:
-            return self._stride_step_fns(graph)
-        return self._allgather_step_fns(graph)
+            return ((),) + self._stride_step_fns(graph)
+        if plan == PLAN_ALLGATHER:
+            return ((),) + self._allgather_step_fns(graph)
+        H = _patterns.halo_radius(graph)
+        kw = self._kernel_kw(graph.kernel)
+        D = len(self.devices)
+
+        def t0(s, o):
+            return _kops.taskbench_step(
+                s[None], o[2][None], o[3][None], **kw)[0]
+
+        def step(s, o, t):
+            return _extend_launch(s[None], o[0][None], o[1][None], halo=H,
+                                  num_devices=D, kw=kw)[0]
+
+        return self._operands(graph, H), t0, step
 
     def _build_plan_stepper(
         self, graph: TaskGraph, plan: str, *,
@@ -1547,9 +1604,7 @@ class PallasStepRuntime(_BspBase):
         self._require_ensemble_support(ensemble)
         S = self._ensemble_steps_per_launch(ensemble)
         if self._is_stacked(ensemble):
-            if S > 1:
-                return self._build_ensemble_stacked_blocked(ensemble, S)
-            return self._build_ensemble_stacked(ensemble)
+            return self._build_halo_stacked(ensemble, S)
         self._record_stacking_degradation(ensemble, S, "tuple")
         if S > 1:
             return self._build_ensemble_tuple_blocked(ensemble, S)
@@ -1581,165 +1636,6 @@ class PallasStepRuntime(_BspBase):
             stacked=False,
         )
 
-    def _build_ensemble_stacked(
-        self, ensemble: GraphEnsemble, *,
-        tile: Optional[Tuple[int, int]] = None,
-    ) -> Callable:
-        """All K members' combines + bodies in ONE megakernel launch/step.
-
-        Under the window combine with no ``block_rows`` the stacked state
-        rides the scan in the carry layout of ``_build_halo`` (``tile``
-        as there); otherwise each step extends it by the ring exchange.
-
-        With ``member_shards`` Dk > 1 the shard_map runs over the 2D
-        (row, member) mesh: the K axis splits Dk ways (so each device
-        holds a (K/Dk, W/Dr, P) slice instead of all K members), rows
-        split over the remaining Dr = D/Dk row devices, and every halo
-        exchange still names AXIS — spanning only its Dr-device row
-        subgroup, never the member axis. Outputs are bit-identical to the
-        replicated path (same per-row arithmetic, only ownership moves).
-        """
-        members = ensemble.members
-        K = len(members)
-        unroll = int(self.options.get("unroll", 1))
-        mesh, dk, Dr = self._stacked_mesh(ensemble)
-        H = max(_patterns.halo_radius(g) for g in members)
-        kw = self._kernel_kw(members[0].kernel)
-        steps = ensemble.steps
-        hetero = ensemble.heterogeneous_steps
-        member_steps = np.asarray(ensemble.member_steps, np.int32)
-        kspec = P(MEMBER_AXIS, AXIS) if dk > 1 else P(None, AXIS)
-        mspec = P(MEMBER_AXIS) if dk > 1 else P()
-
-        ops4 = [self._operands(g, H, block=g.width // Dr) for g in members]
-        idx, wgt, idx0, wgt0 = _stack_operands(ops4)
-
-        carried = kw["combine"] == "window" and "block_rows" not in kw
-        lay = _carry_layout(members[0].width // Dr, H, members[0].payload,
-                            tile) if carried else None
-
-        def megastep(ext_src, i, w):  # (K, S, P), (K, B, D'), (K, B, D')
-            return _kops.taskbench_step(ext_src, i, w, **kw)
-
-        def local_run(local, i, w, i0, w0, msteps):  # local (K, B, P)
-            if carried:
-                return _window_carry_run(
-                    local, w, w0, lay=lay, steps=steps, num_devices=Dr,
-                    kw={k: kw[k] for k in ("kind", "iterations", "scratch")},
-                    unroll=unroll, member_steps=msteps if hetero else None)
-            state = megastep(local, i0, w0)
-            if steps == 1:
-                return state
-
-            def body(s, t):
-                nxt = megastep(_extend_state(s, H, Dr, row_axis=1), i, w)
-                if hetero:  # freeze members whose own T is exhausted
-                    active = (t < msteps)[:, None, None]
-                    nxt = jnp.where(active, nxt, s)
-                return nxt, None
-
-            state, _ = jax.lax.scan(
-                body, state, jnp.arange(1, steps), unroll=unroll
-            )
-            return state
-
-        fn = jax.jit(
-            shard_map(
-                local_run, mesh=mesh, check_vma=False,
-                in_specs=(kspec,) * 5 + (mspec,), out_specs=kspec,
-            )
-        )
-        sh = NamedSharding(mesh, kspec)
-        consts = tuple(
-            jax.device_put(jnp.asarray(a), sh) for a in (idx, wgt, idx0, wgt0)
-        ) + (jax.device_put(jnp.asarray(member_steps),
-                            NamedSharding(mesh, mspec)),)
-
-        def run(inits):
-            out = fn(jax.device_put(jnp.stack(inits), sh), *consts)
-            return tuple(out[k] for k in range(K))
-
-        return run
-
-    def _build_ensemble_stacked_blocked(
-        self, ensemble: GraphEnsemble, S: int
-    ) -> Callable:
-        """All K members share each deep exchange AND each S-step launch."""
-        members = ensemble.members
-        K = len(members)
-        unroll = int(self.options.get("unroll", 1))
-        mesh, dk, Dr = self._stacked_mesh(ensemble)
-        H = max(_patterns.halo_radius(g) for g in members)
-        depth = S * H
-        mode = self._combine_mode()
-        kw0 = self._kernel_kw(members[0].kernel)
-        kwb = dict(kw0, steps_per_launch=S)
-        kwb.pop("block_rows", None)
-        steps = ensemble.steps
-        kspec = P(MEMBER_AXIS, AXIS) if dk > 1 else P(None, AXIS)
-        # acts is (L, K, S): the member axis shards its K slices alongside
-        # the state, so each device only masks the members it owns
-        aspec = P(None, MEMBER_AXIS) if dk > 1 else P()
-
-        ops4 = [self._blocked_operands(g, H, block=g.width // Dr)
-                for g in members]
-        idx, wgt, idx0, wgt0 = _stack_operands(ops4)
-        acts = _act_schedule(ensemble.member_steps, steps, S)  # (L, K, S)
-        pipelined = self._pipeline_active(members[0].width // Dr, S, H,
-                                          members[0].payload)
-        impl = self._halo_impl()
-
-        def local_run(local, i, w, i0, w0, act_seq):  # local (K, B, P)
-            state = _kops.taskbench_step(local, i0, w0, **kw0)
-            if steps == 1:
-                return state
-            B = local.shape[1]
-            if pipelined:
-                # one boundary launch (K row-fused 6*depth-row programs) +
-                # one interior launch per deep exchange — every member
-                # shares both
-                ph = _phase_tables(i, w, depth, Dr, mode)
-                h = _prologue_exchange(state, depth, Dr, impl)
-
-                def pbody(carry, a):  # a: (K, S)
-                    s, hl, hr = carry
-                    s2, h2 = _pipelined_launch(
-                        s, hl, hr, a, ph, depth, Dr, kwb, impl)
-                    return (s2, h2.recv_left, h2.recv_right), None
-
-                (state, _, _), _ = jax.lax.scan(
-                    pbody, (state, h.recv_left, h.recv_right),
-                    act_seq, unroll=unroll)
-                return state
-
-            iext, wext = _extend_tables(i, w, depth, Dr, mode, row_axis=1)
-
-            def body(s, a):  # a: (K, S) per-member per-depth activity
-                ext = _extend_state(s, depth, Dr, row_axis=1)
-                nf = _kops.taskbench_step(ext, iext, wext, a, **kwb)
-                return jax.lax.slice_in_dim(nf, depth, depth + B, axis=1), None
-
-            state, _ = jax.lax.scan(body, state, act_seq, unroll=unroll)
-            return state
-
-        fn = jax.jit(
-            shard_map(
-                local_run, mesh=mesh, check_vma=False,
-                in_specs=(kspec,) * 5 + (aspec,), out_specs=kspec,
-            )
-        )
-        sh = NamedSharding(mesh, kspec)
-        rep = NamedSharding(mesh, aspec)
-        consts = tuple(
-            jax.device_put(jnp.asarray(a), sh) for a in (idx, wgt, idx0, wgt0)
-        ) + (jax.device_put(jnp.asarray(acts), rep),)
-
-        def run(inits):
-            out = fn(jax.device_put(jnp.stack(inits), sh), *consts)
-            return tuple(out[k] for k in range(K))
-
-        return run
-
     def _build_ensemble_tuple(self, ensemble: GraphEnsemble) -> Callable:
         """Mixed specs/shapes/plans: one launch per member, one jitted scan.
 
@@ -1751,31 +1647,9 @@ class PallasStepRuntime(_BspBase):
         members = ensemble.members
         unroll = int(self.options.get("unroll", 1))
         mesh = self._mesh()
-        D = len(self.devices)
         steps = ensemble.steps
-        plans = [self.plan_for(g)[0] for g in members]
-        ops4: List[tuple] = []
-        t0_fns: List[Callable] = []
-        step_fns: List[Callable] = []
-        for g, plan in zip(members, plans):
-            if plan == PLAN_HALO:
-                H = _patterns.halo_radius(g)
-                kw = self._kernel_kw(g.kernel)
-                ops4.append(self._operands(g, H))
-
-                def t0(s, o, kw=kw):
-                    return _kops.taskbench_step(
-                        s[None], o[2][None], o[3][None], **kw)[0]
-
-                def step(s, o, t, H=H, kw=kw):
-                    ext = _extend_state(s, H, D)
-                    return _kops.taskbench_step(
-                        ext[None], o[0][None], o[1][None], **kw)[0]
-            else:
-                ops4.append(())
-                t0, step = self._plan_step_fns(g, plan)
-            t0_fns.append(t0)
-            step_fns.append(step)
+        ops4, t0_fns, step_fns = zip(*(
+            self._member_step_fns(g, self.plan_for(g)[0]) for g in members))
 
         def local_run(states, operands):
             states = tuple(
@@ -1877,14 +1751,10 @@ class PallasStepRuntime(_BspBase):
                         nxt.append(s2[0])
                         nh.append((h2.recv_left, h2.recv_right))
                         continue
-                    B = s.shape[0]
-                    ext = _extend_state(s, dep, D)
                     iext, wext = exts[k]
-                    nf = _kops.taskbench_step(
-                        ext[None], iext[None], wext[None], a[k][None],
-                        **kwbs[k])[0]
-                    nxt.append(
-                        jax.lax.slice_in_dim(nf, dep, dep + B, axis=0))
+                    nxt.append(_serial_launch(
+                        s[None], iext[None], wext[None], a[k][None],
+                        depth=dep, num_devices=D, kwb=kwbs[k])[0])
                     nh.append(())
                 return (tuple(nxt), tuple(nh)), None
 
@@ -1931,9 +1801,11 @@ class PallasStepRuntime(_BspBase):
     def _launch_plan_stacked(
         self, ensemble: GraphEnsemble, S: int
     ) -> EnsembleLaunchPlan:
-        """Host-stepped twin of _build_ensemble_stacked[_blocked]: same
-        kernels, same operands, same act predicate — the scan is simply
-        unrolled to the host so the engine owns the launch loop."""
+        """Host-stepped twin of `_build_halo_stacked` on an ensemble: the
+        same launches (`_extend_launch` at S=1, `_serial_launch` past
+        it), operands and act predicate, with the scan unrolled to the
+        host so the engine owns the launch loop; past S=1 the schedule
+        is always the serial one."""
         members = ensemble.members
         K = len(members)
         mesh, dk, Dr = self._stacked_mesh(ensemble)
@@ -1953,13 +1825,11 @@ class PallasStepRuntime(_BspBase):
         # owning shard writes them) and row-shard over AXIS
         ispec = P(None, AXIS)
 
-        if S > 1:
-            kwb = dict(kw0, steps_per_launch=S)
-            kwb.pop("block_rows", None)
-            ops4 = [self._blocked_operands(g, H, block=B) for g in members]
-        else:
-            ops4 = [self._operands(g, H, block=B) for g in members]
-        idx, wgt, idx0, wgt0 = _stack_operands(ops4)
+        kwb = dict(kw0, steps_per_launch=S)
+        kwb.pop("block_rows", None)
+        operands = self._blocked_operands if S > 1 else self._operands
+        idx, wgt, idx0, wgt0 = _stack_operands(
+            [operands(g, H, block=B) for g in members])
 
         def t0_local(local, i0, w0):  # (K, B, P)
             return _kops.taskbench_step(local, i0, w0, **kw0)
@@ -1967,11 +1837,9 @@ class PallasStepRuntime(_BspBase):
         def launch_local(s, i, w, a):  # a: (K, S), K-sharded with state
             if S > 1:
                 iext, wext = _extend_tables(i, w, depth, Dr, mode, row_axis=1)
-                ext = _extend_state(s, depth, Dr, row_axis=1)
-                nf = _kops.taskbench_step(ext, iext, wext, a, **kwb)
-                return jax.lax.slice_in_dim(nf, depth, depth + B, axis=1)
-            nxt = _kops.taskbench_step(
-                _extend_state(s, H, Dr, row_axis=1), i, w, **kw0)
+                return _serial_launch(s, iext, wext, a, depth=depth,
+                                      num_devices=Dr, kwb=kwb)
+            nxt = _extend_launch(s, i, w, halo=H, num_devices=Dr, kw=kw0)
             # per-member freeze: same predicate the stacked scan applies
             # (act row at S=1 is exactly t < T_k)
             return jnp.where(a[:, 0][:, None, None] > 0, nxt, s)
@@ -1992,7 +1860,6 @@ class PallasStepRuntime(_BspBase):
 
         sh = NamedSharding(mesh, kspec)
         rep = NamedSharding(mesh, aspec)
-        ish = NamedSharding(mesh, ispec)
         t0_fn = jax.jit(shard_map(
             t0_local, mesh=mesh, check_vma=False,
             in_specs=(kspec,) * 3, out_specs=kspec))
@@ -2048,32 +1915,10 @@ class PallasStepRuntime(_BspBase):
         the same mask edit as the stacked plan)."""
         members = ensemble.members
         mesh = self._mesh()
-        D = len(self.devices)
         steps = ensemble.steps
-        plans = [self.plan_for(g)[0] for g in members]
         acts = _act_schedule(ensemble.member_steps, steps, 1)  # (L, K, 1)
-        ops4: List[tuple] = []
-        t0_fns: List[Callable] = []
-        step_fns: List[Callable] = []
-        for g, plan in zip(members, plans):
-            if plan == PLAN_HALO:
-                H = _patterns.halo_radius(g)
-                kw = self._kernel_kw(g.kernel)
-                ops4.append(self._operands(g, H))
-
-                def t0(s, o, kw=kw):
-                    return _kops.taskbench_step(
-                        s[None], o[2][None], o[3][None], **kw)[0]
-
-                def step(s, o, t, H=H, kw=kw):
-                    ext = _extend_state(s, H, D)
-                    return _kops.taskbench_step(
-                        ext[None], o[0][None], o[1][None], **kw)[0]
-            else:
-                ops4.append(())
-                t0, step = self._plan_step_fns(g, plan)
-            t0_fns.append(t0)
-            step_fns.append(step)
+        ops4, t0_fns, step_fns = zip(*(
+            self._member_step_fns(g, self.plan_for(g)[0]) for g in members))
 
         def t0_all(states, operands):
             return tuple(

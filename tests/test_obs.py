@@ -12,15 +12,14 @@ Four layers of coverage:
     (single-device in-process; the 2-device matrix runs in a subprocess);
   * layer spans: pallas_step's build and calls land in the profiler's host
     plane in order and in the process-wide counter table, on every plan;
-  * the off-by-default contract: a disabled tracer's per-span cost times
-    the spans-per-step rate must stay under 1% of a measured step wall.
+  * the off-by-default contract: a runtime with tracing off holds the
+    NULL_TRACER and opens no recording span while it measures.
 """
 import json
 import os
 import subprocess
 import sys
 import textwrap
-import time
 
 import numpy as np
 import pytest
@@ -117,30 +116,23 @@ def test_null_tracer_is_inert():
     assert nt.spans == ()
 
 
-def test_null_tracer_overhead_under_one_percent():
-    """The off-by-default contract: instrumenting a hot path with TWO null
-    spans per step (attrs and all, exactly as the runtimes call it) must
-    cost < 1% of a step wall at the smoke benches' own shape (grain 64)."""
+def test_null_tracer_records_nothing_over_a_measure(monkeypatch):
+    """The off-by-default contract: a runtime built with tracing off holds
+    NULL_TRACER, and a whole ``measure`` of a ``bsp`` graph never opens a
+    recording span (``Tracer.span`` raises if it does)."""
     from repro.core import KernelSpec, TaskGraph, get_runtime
 
-    nt = NULL_TRACER
-    n = 20000
-    t0 = time.perf_counter()
-    for _ in range(n):
-        with nt.span("dispatch", "dispatch", step=0):
-            pass
-        with nt.span("kernel", "compute.interior", step=0):
-            pass
-    per_step_overhead = (time.perf_counter() - t0) / n
+    def recording_span(self, *a, **k):
+        raise AssertionError("a recording span was opened with tracing off")
 
+    monkeypatch.setattr(Tracer, "span", recording_span)
     g = TaskGraph(steps=8, width=64, pattern="stencil_1d", payload=64,
                   kernel=KernelSpec("compute_bound", 64), radius=1, seed=0)
     rt = get_runtime("bsp")
+    assert rt.tracer is NULL_TRACER
     sample, _ = rt.measure(g, reps=2, warmup=1)
-    step_wall = sample.wall_time / g.steps
-    assert per_step_overhead < 0.01 * step_wall, (
-        f"null-tracer cost {per_step_overhead * 1e9:.0f} ns/step vs "
-        f"step wall {step_wall * 1e6:.1f} us")
+    assert sample.wall_time > 0
+    assert rt.tracer.spans == ()
 
 
 # ------------------------------------------------------------- exporters --

@@ -307,15 +307,15 @@ def _planted_init(width, payload, seed):
 def _check_carry_parity(pattern, radius, width, payload, body, *,
                         tile=None, devices=None):
     """The S=1 window loop on its halo-extended carry (chip tiling or
-    interpret mode's (1, 1)) against the per-step extend path it
-    replaced, bit for bit."""
+    interpret mode's (1, 1)) against the per-step extend branch of the
+    same builder, bit for bit."""
     g = TaskGraph(steps=6, width=width, payload=payload, pattern=pattern,
                   radius=radius, seed=5,
                   kernel=KernelSpec(body, 4, scratch=2 * payload))
     x = _planted_init(width, payload, 5)
     rt = get_runtime("pallas_step", devices=devices)
-    out = np.asarray(rt._build_halo(g, tile=tile)(x))
-    ref = np.asarray(rt._build_halo_extend(g)(x))
+    out = np.asarray(rt._build_halo_stacked(g, 1, tile=tile)(x))
+    ref = np.asarray(rt._build_halo_stacked(g, 1, carry=False)(x))
     np.testing.assert_array_equal(out, ref, err_msg=f"{g.describe()} {tile}")
 
 
@@ -331,11 +331,12 @@ def _check_carry_parity_ensemble(hetero, *, tile=None, devices=None):
     ]
     inits = [_planted_init(52, 64, k) for k in range(4)]
     rt = get_runtime("pallas_step", devices=devices)
-    outs = rt._build_ensemble_stacked(GraphEnsemble(members), tile=tile)(
+    outs = rt._build_halo_stacked(GraphEnsemble(members), 1, tile=tile)(
         [jnp.asarray(x) for x in inits])
     for k, (g, x, out) in enumerate(zip(members, inits, outs)):
+        ref = rt._build_halo_stacked(g, 1, carry=False)(x)
         np.testing.assert_array_equal(
-            np.asarray(out), np.asarray(rt._build_halo_extend(g)(x)),
+            np.asarray(out), np.asarray(ref),
             err_msg=f"member {k} T={g.steps} {tile}")
 
 
@@ -351,6 +352,44 @@ def test_pallas_step_s1_carry_bit_identical(case, tile):
 @pytest.mark.parametrize("hetero", [False, True], ids=["uniform", "hetero"])
 def test_pallas_step_s1_carry_stacked_ensemble_bit_identical(hetero, tile):
     _check_carry_parity_ensemble(hetero, tile=tile)
+
+
+#: (id, runtime options, S, pipelined, carry tile): each branch of the
+#: halo builder
+ONE_MEMBER_CASES = [
+    ("s1-carry-interp", {}, 1, False, None),
+    ("s1-carry-chip", {}, 1, False, CHIP_TILE),
+    ("s1-extend-onehot", dict(combine="onehot"), 1, False, None),
+    ("s3-pipelined", dict(steps_per_launch=3), 3, True, None),
+    ("s3-serial", dict(steps_per_launch=3, pipeline=False), 3, False, None),
+]
+
+
+@pytest.mark.parametrize("opts,S,piped,tile",
+                         [c[1:] for c in ONE_MEMBER_CASES],
+                         ids=[c[0] for c in ONE_MEMBER_CASES])
+def test_pallas_step_single_graph_equals_one_member_ensemble(opts, S, piped,
+                                                             tile):
+    """A single graph's program and a one-member ensemble's come from one
+    builder and agree bit for bit on every branch; the chip tile is
+    reached through the builder itself, the other cases through
+    ``build`` and ``build_ensemble``."""
+    g = TaskGraph(steps=8, width=52, payload=64, pattern="nearest",
+                  radius=2, seed=3,
+                  kernel=KernelSpec("compute_bound", 4, scratch=128))
+    x = _planted_init(52, 64, 3)
+    rt = get_runtime("pallas_step", **opts)
+    assert rt._schedule_for_graph(g) == ("halo", S, "")
+    assert rt._pipeline_active(52, S, 2, 64) == piped
+    if tile is None:
+        out = rt.build(g)(x)
+        ens = rt.build_ensemble(GraphEnsemble([g]))([jnp.asarray(x)])[0]
+    else:
+        out = rt._build_halo_stacked(g, S, tile=tile)(x)
+        ens = rt._build_halo_stacked(GraphEnsemble([g]), S, tile=tile)(
+            [jnp.asarray(x)])[0]
+    assert out.shape == ens.shape == x.shape
+    np.testing.assert_array_equal(np.asarray(out), np.asarray(ens))
 
 
 @pytest.mark.parametrize("tile", [None, CHIP_TILE], ids=["interp", "chip"])

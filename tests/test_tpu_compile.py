@@ -228,7 +228,7 @@ def test_s1_halo_loop_touches_the_state_only_in_the_megakernel(
     g = TaskGraph(steps=9, width=W, payload=PAYLOAD, pattern="stencil_1d",
                   kernel=KernelSpec("compute_bound", 1))
     rt = get_runtime("pallas_step", devices=topo.devices[:1])
-    rt._build_halo(g)(jnp.zeros((W, PAYLOAD), jnp.float32))
+    rt._build_halo_stacked(g, 1)(jnp.zeros((W, PAYLOAD), jnp.float32))
     monkeypatch.undo()
     (text,) = compiled
 
